@@ -17,6 +17,8 @@ import argparse
 import json
 from typing import List, Optional, Sequence, Tuple
 
+from repro.launch.compile_cache import enable_compile_cache
+
 Section = Tuple[str, str, object]  # (name, "csv" | "text", thunk)
 
 
@@ -57,6 +59,7 @@ def main() -> None:
                     help="per-trial result cache for --suite (content-keyed; "
                     "re-runs only changed configs)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.suite:
         from . import suite as suite_mod

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import multiprocessing
 import os
 import tempfile
 import time
@@ -211,7 +212,11 @@ def run_suite(trials: Sequence[Trial], workers: int = 1,
         for i in todo:
             results[i] = _run_trial(payloads[i])
     else:
-        with ProcessPoolExecutor(max_workers=workers) as ex:
+        # spawn, never fork: a parent that has initialized a JAX backend
+        # holds threads (and possibly a chip) that a forked child inherits
+        with ProcessPoolExecutor(
+                max_workers=workers,
+                mp_context=multiprocessing.get_context("spawn")) as ex:
             futs = {ex.submit(_run_trial, payloads[i]): i for i in todo}
             for fut in as_completed(futs):
                 results[futs[fut]] = fut.result()

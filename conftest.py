@@ -3,10 +3,11 @@
 Two jobs:
 
 1. **JAX persistent compilation cache** — the tier-1 suite's wall time is
-   dominated by XLA compiles of the model smoke tests; caching them under
-   ``.jax_cache/`` (gitignored) makes every rerun start warm.  Set via
-   environment variables (before jax initializes) so subprocess tests
-   inherit the same cache.
+   dominated by XLA compiles of the model smoke tests; caching them where
+   :func:`repro.launch.compile_cache.compile_cache_dir` says (``.jax_cache/``
+   in the checkout unless ``JAX_COMPILATION_CACHE_DIR`` is set) makes every
+   rerun start warm.  Set via environment variables (before jax
+   initializes) so subprocess tests inherit the same cache.
 
 2. **Suite runtime budget** — now that the network tests run in virtual
    time, the default suite has a wall-clock budget (satisfying the CI gate:
@@ -18,9 +19,9 @@ import time
 
 import pytest
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
+from repro.launch.compile_cache import compile_cache_dir
+
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
 
 _SESSION_T0 = time.monotonic()

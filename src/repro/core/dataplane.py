@@ -43,6 +43,7 @@ class FeedStats:
     host_alloc_ns: int = 0    # host-side production time on the critical path
     empty_polls: int = 0
     occupancy_sum: int = 0    # ring occupancy integral (for avg occupancy)
+    devices: int = 0          # devices the last batch was placed across
 
     @property
     def avg_occupancy(self) -> float:
@@ -54,6 +55,11 @@ class FeedStats:
 
 def _tree_bytes(tree: Any) -> int:
     return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
+def _tree_devices(tree: Any) -> int:
+    return max(len(x.sharding.device_set)
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 class KernelStackFeed:
@@ -86,6 +92,7 @@ class KernelStackFeed:
         self.stats.put_ns += t2 - t1
         self.stats.batches += 1
         self.stats.bytes += _tree_bytes(host)
+        self.stats.devices = _tree_devices(dev)
         return dev
 
     def stop(self) -> None:
@@ -194,6 +201,7 @@ class BypassDataplane:
                     self._refill()  # keep the ring full before returning
                     self.stats.batches += 1
                     self.stats.bytes += _tree_bytes(dev)
+                    self.stats.devices = _tree_devices(dev)
                     self.stats.occupancy_sum += len(self._inflight) + 1
                     self.stats.wait_ns += time.perf_counter_ns() - t_start  # simlint: disable=SL001 -- wall-clock feed mode
                     return dev

@@ -58,7 +58,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from ..kernels.epoch_fastpath import (epoch_pass_np, get_epoch_pass_jax,
+from ..kernels.epoch_fastpath import (epoch_pass_jax, epoch_pass_np,
                                       serialization_ns_vec,
                                       wire_arrival_pass_np)
 from .packet import DEFAULT_DST_IP, DEFAULT_SRC_IP_BASE, swap_macs_vec
@@ -174,7 +174,7 @@ class EpochRunInfo:
     bit-identical across engines).  Pass an instance to :func:`run_epoch_sim`
     to learn whether the fast path ran and why it fell back."""
 
-    engine: str = "epoch"
+    engine: str = "epoch"   # what ran: "epoch", "epoch-jit" or "event"
     fastpath: bool = False
     fallback_reason: Optional[str] = None
     used_jax: bool = False
@@ -364,12 +364,8 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
         return _Plan(n=0, start=start, final_now=start)
     times_abs = times + start
 
-    pass_fn = epoch_pass_np
-    if use_jax:
-        jax_pass = get_epoch_pass_jax()
-        if jax_pass is not None:
-            pass_fn = jax_pass
-            info.used_jax = True
+    pass_fn = epoch_pass_jax if use_jax else epoch_pass_np
+    info.used_jax = use_jax
     if epoch_ns is None:
         epoch_ns = default_epoch_ns(lg.ports, times_abs)
 
@@ -581,14 +577,15 @@ def run_epoch_sim(loadgen, server, pattern, duration_s: float = 0.25,
     fast path cannot reproduce bit-identically.
 
     Drop-in replacement for :meth:`~repro.core.loadgen.LoadGen.run_sim`
-    (same clock/sched resolution, same RunReport).  ``use_jax`` routes the
-    array passes through the jit-compiled JAX kernel when available;
+    (same clock/sched resolution, same RunReport).  ``use_jax`` runs the
+    array passes on the JAX device (:func:`epoch_pass_jax`); an error there
+    propagates — it never falls back to numpy or to the event loop.
     ``epoch_ns`` overrides the epoch length (default: see
-    :func:`default_epoch_ns`); ``info`` receives fast-path/fallback details.
+    :func:`default_epoch_ns`); ``info`` receives fast-path/fallback details,
+    with ``info.engine`` naming the engine that ran.
     """
     if info is None:
         info = EpochRunInfo()
-    info.engine = "epoch-jit" if use_jax else "epoch"
     if clock is None:
         clock = getattr(server, "clock", None)
     if clock is None:
@@ -608,13 +605,18 @@ def run_epoch_sim(loadgen, server, pattern, duration_s: float = 0.25,
         else:
             plan = _build_plan(loadgen, server, pattern, clock, duration_s,
                                epoch_ns, use_jax, info)
-    except Exception as exc:  # planning is pure — always safe to fall back
+    except Exception as exc:  # numpy planning is pure — safe to fall back
+        if use_jax:
+            raise
         info.fallback_reason = f"planning failed: {exc!r}"
         plan = None
     if plan is None:
+        info.engine = "event"
         info.fastpath = False
+        info.used_jax = False
         return loadgen.run_sim(server, pattern, duration_s=duration_s,
                                clock=clock, max_rounds=max_rounds,
                                sched=sched)
+    info.engine = "epoch-jit" if use_jax else "epoch"
     info.fastpath = True
     return _commit(loadgen, server, pattern, clock, plan)

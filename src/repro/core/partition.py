@@ -802,9 +802,9 @@ class MpPartitionEngine:
         self.n_windows = 0
         self.final_clock_ns = 0
         groups = assign_groups(n_domains, n_workers)
-        methods = multiprocessing.get_all_start_methods()
-        ctx = multiprocessing.get_context(
-            "fork" if "fork" in methods else "spawn")
+        # spawn, never fork: a parent that has initialized a JAX backend
+        # holds threads (and possibly a chip) that a forked child inherits
+        ctx = multiprocessing.get_context("spawn")
         self._owner: List[List[int]] = groups
         self._ownset = [set(g) for g in groups]
         self._conns = []

@@ -12,11 +12,9 @@ needs mid-run access to the server).  The traffic mode selects the drive:
 """
 from __future__ import annotations
 
-from typing import Callable, List, Tuple
+from typing import Callable, List, Optional, Tuple
 
 import numpy as np
-
-from typing import Optional
 
 from repro.core import (EpochRunInfo, EthDev, NetworkStack, PARTITIONED_REASON,
                         PartitionRunInfo, RunReport, TrafficPattern,
@@ -40,11 +38,14 @@ def make_server_factory(
     return factory
 
 
-def run_testbed(tb: Testbed) -> RunReport:
+def run_testbed(tb: Testbed, *,
+                info: Optional[EpochRunInfo] = None) -> RunReport:
     """Drive an already-built testbed per its config's traffic mode
     (``closed_loop`` or ``open_loop``; ``msb`` needs fresh testbeds per trial
     — use :func:`run_experiment`).  ``cfg.traffic.sim_time`` selects virtual
-    time (the testbed's SimClock, deterministic) vs. wall-clock pacing."""
+    time (the testbed's SimClock, deterministic) vs. wall-clock pacing.
+    ``info`` receives the epoch engine's run details (``open_loop`` with
+    ``engine`` "epoch" or "epoch-jit")."""
     t = tb.cfg.traffic
     if t.mode == "closed_loop":
         rng = (np.random.default_rng(t.payload_seed)
@@ -64,7 +65,8 @@ def run_testbed(tb: Testbed) -> RunReport:
                 return run_epoch_sim(tb.loadgen, tb.server, pattern,
                                      duration_s=t.duration_s, clock=tb.clock,
                                      sched=tb.sched,
-                                     use_jax=(t.engine == "epoch-jit"))
+                                     use_jax=(t.engine == "epoch-jit"),
+                                     info=info)
             return tb.loadgen.run_sim(tb.server, pattern,
                                       duration_s=t.duration_s, clock=tb.clock,
                                       sched=tb.sched)
@@ -73,11 +75,13 @@ def run_testbed(tb: Testbed) -> RunReport:
     raise ValueError(f"run_testbed cannot drive traffic mode {t.mode!r}")
 
 
-def run_experiment(cfg: ExperimentConfig) -> RunReport:
-    """Build + run one experiment from config alone."""
+def run_experiment(cfg: ExperimentConfig, *,
+                   info: Optional[EpochRunInfo] = None) -> RunReport:
+    """Build + run one experiment from config alone (``info``: see
+    :func:`run_testbed`)."""
     t = cfg.traffic
     if t.mode in ("closed_loop", "open_loop"):
-        return run_testbed(Testbed.build(cfg))
+        return run_testbed(Testbed.build(cfg), info=info)
     # msb: ramp + bisect over fresh testbeds
     gbps, reports = find_max_sustainable_bandwidth(
         make_server_factory(cfg),
@@ -122,7 +126,7 @@ def run_topology_experiment(cfg: TopologyConfig, *,
             partition_info.n_workers = 1
         return Cluster.build(cfg).run()
     if info is not None and cfg.traffic.engine != "event":
-        info.engine = cfg.traffic.engine
+        info.engine = "event"
         info.fastpath = False
         info.fallback_reason = PARTITIONED_REASON
     return run_partitioned_topology(cfg, info=partition_info)

@@ -19,14 +19,16 @@ passes instead of per-event Python rounds:
   vectorized cost table, consumed by the harvest cascade.
 
 The numpy implementation is the portable reference and the default.  The JAX
-variant jit-compiles the same integer scan; it is only *used* when 64-bit
-mode is available (``jax_enable_x64``), because the engine's contract is
-bit-identical timestamps and int32 would overflow ns arithmetic.
+variant (:func:`epoch_pass_jax`) runs the same integer scan on the device in
+int32, on offsets from the start of each slice, and returns results
+bit-identical to the numpy pass.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -34,7 +36,7 @@ __all__ = [
     "wire_arrival_pass_np",
     "epoch_pass_np",
     "pmd_burst_cost_table",
-    "get_epoch_pass_jax",
+    "epoch_pass_jax",
 ]
 
 
@@ -101,67 +103,70 @@ def pmd_burst_cost_table(max_burst: int, poll_cycles: int,
     return table
 
 
-_JAX_PASS = None
-_JAX_TRIED = False
+# slices are padded to a power-of-two length (at least this) before the
+# device pass, so a run compiles once per bucket instead of once per length
+_MIN_BUCKET = 1024
+_I32_LIMIT = 2**31
 
 
-def get_epoch_pass_jax():
-    """The jit-compiled epoch pass, or None when JAX (with 64-bit integer
-    mode) is unavailable.  Signature matches :func:`epoch_pass_np`.
+@jax.jit
+def _scan_i32(handed, ser):
+    # the wire recursion of wire_arrival_pass_np, rebased so that the
+    # carried-in busy time is 0: see epoch_pass_jax
+    cum = jnp.cumsum(ser)
+    m = jnp.maximum(jax.lax.cummax(handed - (cum - ser)), 0)
+    return m + cum
 
-    The serialization rounding stays in numpy (cheap, and Python/numpy
-    half-to-even is the reference); the jitted part is the integer max-plus
-    scan + steering gather — exact in int64, so results are bit-identical to
-    the numpy pass and the engine can treat the two as interchangeable.
+
+@jax.jit
+def _gather(table, ids):
+    return table[ids]
+
+
+def _pad_to_bucket(x: np.ndarray) -> np.ndarray:
+    n = len(x)
+    size = max(_MIN_BUCKET, 1 << (n - 1).bit_length())
+    return np.concatenate([x, np.zeros(size - n, dtype=x.dtype)])
+
+
+def epoch_pass_jax(
+    handed_ns: np.ndarray,
+    ser_ns: np.ndarray,
+    busy0_ns: int,
+    latency_ns: int,
+    flow_queue_table: Optional[np.ndarray],
+    flow_ids: Optional[np.ndarray],
+) -> Tuple[np.ndarray, int, Optional[np.ndarray]]:
+    """:func:`epoch_pass_np` on the default JAX device, bit-identical.
+
+    The scan runs in int32 on offsets from ``base = max(handed[0], busy0)``
+    (a TPU has no native int64, and its compiler fails on the int64 scan at
+    epoch-slice sizes).  With that base the carried-in busy time becomes 0:
+    an emission before ``base`` gives a negative ``t_j - S_{j-1}`` that the
+    ``max`` with 0 discards either way, so it is clamped to 0.  Every value
+    the scan forms then lies in ``[-sum(ser), span + sum(ser)]``; a slice
+    whose bound reaches 2**31 ns raises instead of wrapping.  Slices are
+    zero-padded at the end to a power-of-two length: the scan is a prefix
+    computation, so the padding leaves the first ``n`` results unchanged.
     """
-    global _JAX_PASS, _JAX_TRIED
-    if _JAX_TRIED:
-        return _JAX_PASS
-    _JAX_TRIED = True
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-
-        @jax.jit
-        def _scan(handed, ser, busy0, latency):
-            cum = jnp.cumsum(ser)
-            pre = handed - (cum - ser)
-            m = jnp.maximum(jax.lax.cummax(pre), busy0)
-            ends = m + cum
-            return ends + latency, ends[-1]
-
-        @jax.jit
-        def _gather(table, ids):
-            return table[ids]
-
-        def epoch_pass_jax(handed_ns, ser_ns, busy0_ns, latency_ns,
-                           flow_queue_table, flow_ids):
-            if len(handed_ns) == 0:
-                return np.empty(0, dtype=np.int64), int(busy0_ns), None
-            # 64-bit mode is scoped to this call: ns timestamps overflow
-            # int32, and the engine's contract is bit-identical results
-            with enable_x64():
-                arr, busy = _scan(jnp.asarray(handed_ns, dtype=jnp.int64),
-                                  jnp.asarray(ser_ns, dtype=jnp.int64),
-                                  jnp.int64(busy0_ns), jnp.int64(latency_ns))
-                queues = None
-                if flow_queue_table is not None and flow_ids is not None:
-                    queues = np.asarray(_gather(
-                        jnp.asarray(flow_queue_table), jnp.asarray(flow_ids)))
-                arr = np.asarray(arr)
-                busy = int(busy)
-            return arr, busy, queues
-
-        # smoke-verify exactness against the reference once, on a case with
-        # wire queueing; any divergence (e.g. x64 quietly off) disables JAX
-        h = np.array([0, 5, 5, 40], dtype=np.int64)
-        s = np.array([10, 10, 10, 10], dtype=np.int64)
-        want, wb = wire_arrival_pass_np(h, s, 3, 7)
-        got, gb, _ = epoch_pass_jax(h, s, 3, 7, None, None)
-        if not (np.array_equal(want, got) and wb == gb):  # pragma: no cover
-            return None
-        _JAX_PASS = epoch_pass_jax
-    except Exception:  # pragma: no cover - jax not installed / broken
-        _JAX_PASS = None
-    return _JAX_PASS
+    n = len(handed_ns)
+    if n == 0:
+        return np.empty(0, dtype=np.int64), int(busy0_ns), None
+    handed = np.asarray(handed_ns, dtype=np.int64)
+    ser = np.asarray(ser_ns, dtype=np.int64)
+    base = max(int(handed[0]), int(busy0_ns))
+    rel = np.maximum(handed - base, 0)
+    bound = int(rel[-1]) + int(ser.sum())
+    if bound >= _I32_LIMIT:
+        raise ValueError(
+            f"epoch slice spans {bound} ns from its base; the int32 device "
+            f"pass holds at most {_I32_LIMIT - 1} ns (use engine='epoch')")
+    ends = np.asarray(_scan_i32(_pad_to_bucket(rel.astype(np.int32)),
+                                _pad_to_bucket(ser.astype(np.int32))))[:n]
+    ends = ends.astype(np.int64) + base
+    queues = None
+    if flow_queue_table is not None and flow_ids is not None:
+        ids = _pad_to_bucket(np.asarray(flow_ids).astype(np.int32))
+        queues = np.asarray(_gather(
+            np.asarray(flow_queue_table).astype(np.int32), ids))[:n]
+    return ends + np.int64(latency_ns), int(ends[-1]), queues

@@ -10,6 +10,13 @@ Every op has up to three interchangeable implementations:
                  interpret mode against ``ref`` in tests.
 
 Dispatch: ``impl="auto"`` picks pallas on TPU backends, chunked elsewhere.
+``REPRO_FORCE_IMPL`` overrides that choice, and ``REPRO_PALLAS_INTERPRET=1``
+runs every Pallas kernel in interpret mode (a CPU rehearsal of the TPU path).
+
+Training through ``impl="pallas"`` attention differentiates a
+``custom_vjp``: the Pallas kernel computes the forward, and the backward is
+the VJP of the XLA path (:func:`xla_attention_impl` names which one), which
+recomputes the scores chunk by chunk instead of saving them.
 """
 from __future__ import annotations
 
@@ -19,6 +26,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.parallel.axes import current_mesh, current_rules
 
 from . import ref as _ref
 
@@ -27,6 +37,32 @@ def _auto_impl() -> str:
     if os.environ.get("REPRO_FORCE_IMPL"):
         return os.environ["REPRO_FORCE_IMPL"]
     return "pallas" if jax.default_backend() == "tpu" else "chunked"
+
+
+def _interpret(interpret: bool) -> bool:
+    return interpret or os.environ.get("REPRO_PALLAS_INTERPRET") == "1"
+
+
+def _per_batch_shard(fn, *args, batched: Tuple[bool, ...]):
+    """Call a Pallas kernel once per shard of the batch axes of the active
+    mesh.  GSPMD cannot partition a Mosaic kernel, so under a mesh the call
+    goes through ``shard_map``; every kernel here is independent across the
+    batch, and every output leads with it.  ``batched`` marks which
+    arguments lead with the batch axis (the others are replicated).  A batch
+    the mesh cannot divide runs replicated."""
+    mesh, rules = current_mesh(), current_rules()
+    if mesh is None or rules is None:
+        return fn(*args)
+    axes = rules.resolve("batch")
+    names = (axes,) if isinstance(axes, str) else tuple(axes or ())
+    size = 1
+    for a in names:
+        size *= mesh.shape[a]
+    n = next(x.shape[0] for x, b in zip(args, batched) if b)
+    bspec = P(axes) if names and n % size == 0 else P()
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(bspec if b else P() for b in batched),
+        out_specs=bspec, check_vma=False)(*args)
 
 
 # =============================================================================
@@ -184,6 +220,52 @@ def _paired_causal_attention(
 NEG_INF_PAIRED = -1e30
 
 
+def xla_attention_impl(q_len: int, kv_len: int, *, causal: bool = True,
+                       window: int = 0, q_offset: int = 0) -> str:
+    """Which XLA attention path runs for these shapes: ``"paired"`` (the
+    exact-flops causal pair schedule) or ``"chunked"``."""
+    if (causal and window == 0 and q_offset == 0 and q_len == kv_len
+            and q_len > 1 and os.environ.get("REPRO_NO_PAIRED") != "1"):
+        return "paired"
+    return "chunked"
+
+
+def _xla_attention(q, k, v, *, causal: bool, window: int, q_offset: int,
+                   scale: float, q_chunk: int) -> jnp.ndarray:
+    if xla_attention_impl(q.shape[1], k.shape[1], causal=causal,
+                          window=window, q_offset=q_offset) == "paired":
+        return _paired_causal_attention(q, k, v, scale=scale, chunk=q_chunk)
+    return _chunked_attention(q, k, v, causal=causal, window=window,
+                              q_offset=q_offset, scale=scale, q_chunk=q_chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _pallas_attention(q, k, v, causal, window, q_offset, scale, q_chunk,
+                      interpret):
+    from .flash_attention import flash_attention_pallas
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  q_offset=q_offset, softmax_scale=scale,
+                                  interpret=interpret)
+
+
+def _pallas_attention_fwd(q, k, v, causal, window, q_offset, scale, q_chunk,
+                          interpret):
+    out = _pallas_attention(q, k, v, causal, window, q_offset, scale,
+                            q_chunk, interpret)
+    return out, (q, k, v)
+
+
+def _pallas_attention_bwd(causal, window, q_offset, scale, q_chunk,
+                          interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(
+        _xla_attention, causal=causal, window=window, q_offset=q_offset,
+        scale=scale, q_chunk=q_chunk), *res)
+    return vjp(g)
+
+
+_pallas_attention.defvjp(_pallas_attention_fwd, _pallas_attention_bwd)
+
+
 def flash_attention(
     q: jnp.ndarray,  # (B, Sq, H, Dh)
     k: jnp.ndarray,  # (B, Skv, Hkv, Dh)
@@ -203,17 +285,14 @@ def flash_attention(
         return _ref.mha(q, k, v, causal=causal, window=window, q_offset=q_offset,
                         softmax_scale=scale)
     if impl == "pallas":
-        from .flash_attention import flash_attention_pallas
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_offset=q_offset, softmax_scale=scale,
-                                      interpret=interpret)
-    if (impl in ("chunked", "paired") and causal and window == 0
-            and q_offset == 0 and q.shape[1] == k.shape[1] and q.shape[1] > 1
-            and os.environ.get("REPRO_NO_PAIRED") != "1"):
-        # causal full attention: exact-flops pair schedule (no masked waste)
-        return _paired_causal_attention(q, k, v, scale=scale, chunk=q_chunk)
-    return _chunked_attention(q, k, v, causal=causal, window=window,
-                              q_offset=q_offset, scale=scale, q_chunk=q_chunk)
+        interpret = _interpret(interpret)
+        return _per_batch_shard(
+            lambda q, k, v: _pallas_attention(q, k, v, causal, window,
+                                              q_offset, scale, q_chunk,
+                                              interpret),
+            q, k, v, batched=(True, True, True))
+    return _xla_attention(q, k, v, causal=causal, window=window,
+                          q_offset=q_offset, scale=scale, q_chunk=q_chunk)
 
 
 # =============================================================================
@@ -234,8 +313,10 @@ def decode_attention(
     impl = _auto_impl() if impl == "auto" else impl
     if impl == "pallas":
         from .decode_attention import decode_attention_pallas
-        return decode_attention_pallas(q, k_cache, v_cache, cache_len,
-                                       softmax_scale=scale, interpret=interpret)
+        return _per_batch_shard(
+            functools.partial(decode_attention_pallas, softmax_scale=scale,
+                              interpret=_interpret(interpret)),
+            q, k_cache, v_cache, cache_len, batched=(True,) * 4)
     # chunked == ref math here (scores are (B,H,S): already memory-linear)
     return _ref.decode_attention(q, k_cache, v_cache, cache_len,
                                  softmax_scale=scale)
@@ -257,7 +338,12 @@ def rglru_scan(
     impl = _auto_impl() if impl == "auto" else impl
     if impl == "pallas":
         from .rglru_scan import rglru_scan_pallas
-        return rglru_scan_pallas(x, a_log, h0=h0, interpret=interpret)
+        fn = functools.partial(rglru_scan_pallas,
+                               interpret=_interpret(interpret))
+        if h0 is None:
+            return _per_batch_shard(fn, x, a_log, batched=(True, True))
+        return _per_batch_shard(lambda x, a, h: fn(x, a, h0=h), x, a_log, h0,
+                                batched=(True, True, True))
     if impl == "ref":
         hs = _ref.rglru(x, a_log)
         if h0 is not None:
@@ -319,8 +405,10 @@ def ssd_scan(
     impl = _auto_impl() if impl == "auto" else impl
     if impl == "pallas":
         from .ssd_scan import ssd_scan_pallas
-        return ssd_scan_pallas(x, dt, A, Bmat, Cmat, chunk=chunk, h0=h0,
-                               interpret=interpret)
+        return _per_batch_shard(
+            functools.partial(ssd_scan_pallas, chunk=chunk, h0=h0,
+                              interpret=_interpret(interpret)),
+            x, dt, A, Bmat, Cmat, batched=(True, True, False, True, True))
     if impl == "ref":
         y = _ref.ssd(x, dt, A, Bmat, Cmat)
         return y, jnp.zeros((x.shape[0], x.shape[2], x.shape[3], Bmat.shape[-1]),
@@ -427,5 +515,5 @@ def burst_gather(
     if impl == "pallas":
         from .burst_gather import burst_gather_pallas
         return burst_gather_pallas(arena, slots, lengths, out_width,
-                                   interpret=interpret)
+                                   interpret=_interpret(interpret))
     return _ref.burst_gather(arena, slots, lengths, out_width)

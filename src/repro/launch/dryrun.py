@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.inputs import input_specs
 from repro.launch.mesh import make_production_mesh, rules_for
 from repro.models import lm
@@ -171,6 +172,7 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="append JSONL records here")
     ap.add_argument("--lower-only", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.all:
         cells = all_cells()

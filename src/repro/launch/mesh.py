@@ -2,37 +2,25 @@
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — smoke tests must keep seeing 1 device.
-
-``AxisType`` only exists in jax >= 0.5; on older jax every mesh axis is
-implicitly Auto, so the compat helpers below simply omit the argument.  All
-repo code (and the subprocess test scripts) build meshes through them
-instead of importing ``jax.sharding.AxisType`` directly.
 """
 from __future__ import annotations
 
 from typing import Dict, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # jax <= 0.4.x: axes are Auto by construction
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.parallel.axes import (AxisRules, multi_pod_rules, pure_fsdp_rules,
                                  single_pod_rules)
 
 
 def auto_axis_types_kw(n_axes: int) -> Dict[str, Tuple]:
-    """``{"axis_types": (Auto,) * n}`` where supported, else ``{}``."""
-    if AxisType is None:
-        return {}
+    """``{"axis_types": (Auto,) * n}``: every mesh axis Auto."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with every axis Auto, on any supported jax."""
+    """``jax.make_mesh`` with every axis Auto."""
     return jax.make_mesh(tuple(shape), tuple(axes),
                          **auto_axis_types_kw(len(axes)))
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.telemetry import LatencyRecorder
 from repro.models import lm
 from repro.models.registry import ARCHS, get_config, get_smoke_config
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if not cfg.has_decode:

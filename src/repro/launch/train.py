@@ -13,6 +13,7 @@ import argparse
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh, rules_for
 from repro.models.registry import ARCHS, get_config, get_smoke_config
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dcfg = DataConfig(seq_len=args.seq_len, global_batch=args.global_batch,

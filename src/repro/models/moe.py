@@ -32,13 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: top-level API, replication check renamed to check_vma
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # jax <= 0.5: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from repro.parallel.axes import current_mesh, current_rules
 from .config import ModelConfig
 from .layers import Params, _normal, cdt, dt, init_mlp, apply_mlp
@@ -178,10 +171,7 @@ def _moe_shard_body(cfg: ModelConfig, capacity: int, e_loc: int, fp: int,
             out_buf = _expert_ffn(cfg, wg_f, wu_f, wd_f, buf).reshape(-1, D)
         else:
             # weight-stationary: contract this shard's D-slice, psum partials
-            # (psum of a literal == axis size; lax.axis_size is jax >= 0.6)
-            n_dp = (jax.lax.axis_size("data")
-                    if hasattr(jax.lax, "axis_size")
-                    else jax.lax.psum(1, "data"))
+            n_dp = jax.lax.axis_size("data")
             d_loc = D // n_dp
             d_lo = jax.lax.axis_index("data") * d_loc
             buf_d = jax.lax.dynamic_slice_in_dim(buf, d_lo, d_loc, axis=2)
@@ -310,11 +300,11 @@ def apply_moe(cfg: ModelConfig, p: Params, x: jnp.ndarray
         xspec = P(batch_axes, None, None)
         wspec = P("model", None, "data", None)
         wdspec = P("model", None, None, "data")
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(xspec, P(), wspec, wspec, wdspec),
             out_specs=(xspec, P()),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     else:
         y, aux = _moe_compute_local(cfg, p, x)

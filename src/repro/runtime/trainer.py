@@ -123,11 +123,19 @@ class TrainerRuntime:
                 state = self.maybe_restore(state)
 
             step_fn = make_train_step(self.cfg, self.opt_cfg)
+            bshard = None
             if self.mesh is not None:
                 pshard, oshard = self._shardings(state.params)
+                # commit the state to the step's shardings up front: the
+                # step's own outputs come back committed, and a change from
+                # uncommitted inputs at step 1 would compile the step twice
+                state = TrainerState(
+                    params=jax.device_put(state.params, pshard),
+                    opt_state=jax.device_put(state.opt_state, oshard),
+                    step=state.step)
                 probe = stream_factory(self.cfg, self.dcfg)(0, 1)
                 bshard = make_shardings(
-                    make_batch_specs(next(iter([next(probe)])), self.rules, self.mesh),
+                    make_batch_specs(next(probe), self.rules, self.mesh),
                     self.mesh)
                 jitted = jax.jit(step_fn, in_shardings=(pshard, oshard, bshard),
                                  donate_argnums=(0, 1))
@@ -137,8 +145,10 @@ class TrainerRuntime:
             factory = stream_factory(self.cfg, self.dcfg,
                                      start_step=state.step,
                                      n_steps=tcfg.steps - state.step)
+            # the feed transfers each batch straight into the step's batch
+            # sharding (no single-device landing and reshard in the step)
             feed = make_feed(tcfg.feed, factory, depth=tcfg.feed_depth,
-                             ports=tcfg.feed_ports)
+                             ports=tcfg.feed_ports, sharding=bshard)
             self._feed = feed
             t_start = time.perf_counter()
             try:
@@ -161,11 +171,11 @@ class TrainerRuntime:
                     if state.step % tcfg.log_every == 0 or state.step == 1:
                         m = {k: float(v) for k, v in metrics.items()}
                         m["step"] = state.step
-                        m["wall_s"] = round(time.perf_counter() - t_start, 2)
+                        m["wall_s"] = time.perf_counter() - t_start
                         self.metrics_log.append(m)
                         print(f"[trainer] step {state.step}: "
                               f"loss={m['loss']:.4f} gnorm={m['grad_norm']:.3f} "
-                              f"({m['wall_s']}s)")
+                              f"({m['wall_s']:.2f}s)")
                     if (self.ckpt is not None
                             and state.step % tcfg.ckpt_every == 0):
                         self.ckpt.save(state.step,

@@ -120,14 +120,81 @@ def test_epoch_engine_falls_back_and_matches(name, pattern, dur, kw):
 
 
 def test_epoch_jit_matches_when_available():
-    from repro.kernels.epoch_fastpath import get_epoch_pass_jax
-    if get_epoch_pass_jax() is None:
-        pytest.skip("JAX (with exact int64 pass) unavailable")
     pattern = TrafficPattern(rate_gbps=40.0, packet_size=1518, kind="poisson",
                              seed=7)
     ev, ep, info = run_pair(pattern, 0.002, use_jax=True)
     assert info.fastpath and info.used_jax
     assert ev == ep
+
+
+def test_epoch_jit_raises_when_device_pass_fails(monkeypatch):
+    """A failing device pass propagates: no numpy pass, no event loop."""
+    import repro.core.fastpath as fp
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device pass unavailable")
+
+    monkeypatch.setattr(fp, "epoch_pass_jax", broken)
+    server, ports, clock = build()
+    info = EpochRunInfo()
+    with pytest.raises(RuntimeError, match="device pass unavailable"):
+        run_epoch_sim(LoadGen(ports), server,
+                      TrafficPattern(rate_gbps=40.0, packet_size=1518),
+                      duration_s=0.001, clock=clock, use_jax=True, info=info)
+    assert not info.fastpath
+    assert clock.now_ns == 0  # the event loop never ran
+
+
+@pytest.mark.parametrize("engine,pattern,ran", [
+    ("epoch", TrafficPattern(rate_gbps=40.0, packet_size=1518), "epoch"),
+    ("epoch-jit", TrafficPattern(rate_gbps=40.0, packet_size=1518),
+     "epoch-jit"),
+    # 64B @ 100G overloads 4 lcores: the exact fallback runs the event loop
+    ("epoch-jit", TrafficPattern(rate_gbps=100.0, packet_size=64), "event"),
+], ids=["epoch", "epoch-jit", "epoch-jit-fallback"])
+def test_info_engine_names_what_ran(engine, pattern, ran):
+    cfg = ExperimentConfig(
+        pool=PoolConfig(n_slots=8192, slot_size=2048),
+        ports=(PortConfig(n_queues=4, ring_size=1024,
+                          writeback_threshold=32),),
+        stack=StackConfig(kind="bypass", burst_size=64, n_lcores=4),
+        traffic=TrafficConfig(mode="open_loop", rate_gbps=pattern.rate_gbps,
+                              packet_size=pattern.packet_size,
+                              duration_s=0.0005, engine=engine))
+    info = EpochRunInfo()
+    rep = run_experiment(cfg, info=info)
+    assert info.engine == ran
+    assert info.fastpath == (ran != "event")
+    assert info.used_jax == (ran == "epoch-jit")
+    assert report_key(rep) == report_key(
+        run_experiment(cfg.with_traffic(engine="event")))
+
+
+def test_epoch_jit_one_compile_per_bucket():
+    """Slices of different lengths share a padded power-of-two shape."""
+    from repro.kernels import epoch_fastpath as ef
+    rng = np.random.default_rng(0)
+    compiled_before = ef._scan_i32._cache_size()
+    for n in (1500, 1900, 2048):
+        t = np.sort(rng.integers(0, 10**6, size=n)).astype(np.int64)
+        s = rng.integers(1, 200, size=n).astype(np.int64)
+        want = ef.wire_arrival_pass_np(t, s, 123_456, 50)
+        got, busy, _ = ef.epoch_pass_jax(t, s, 123_456, 50, None, None)
+        assert np.array_equal(want[0], got) and want[1] == busy
+    assert ef._scan_i32._cache_size() - compiled_before <= 1
+
+
+def test_epoch_jit_rebase_is_exact_and_refuses_int32_overflow():
+    from repro.kernels import epoch_fastpath as ef
+    base = 5 * 10**12  # absolute times far beyond int32
+    t = base + np.array([0, 5, 5, 40, 2**30], dtype=np.int64)
+    s = np.array([10, 10, 10, 10, 7], dtype=np.int64)
+    for busy0 in (0, base + 3, base + 10**9):  # idle, queued, long backlog
+        want = ef.wire_arrival_pass_np(t, s, busy0, 7)
+        got, busy, _ = ef.epoch_pass_jax(t, s, busy0, 7, None, None)
+        assert np.array_equal(want[0], got) and want[1] == busy
+    with pytest.raises(ValueError, match="int32"):
+        ef.epoch_pass_jax(t, s + 2**30, 0, 7, None, None)
 
 
 # -- engine equivalence through run_experiment (paper-config shapes) ----------

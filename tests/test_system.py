@@ -147,3 +147,31 @@ def test_trainer_checkpoint_restart_determinism(tmp_path,
     for s in (5, 6):
         assert abs(full[s] - resumed[s]) < 1e-4, \
             f"step {s}: {full[s]} vs {resumed[s]} — restart not deterministic"
+
+
+@pytest.mark.parametrize("feed", ["bypass", "kernel"])
+def test_trainer_on_a_mesh_compiles_the_step_once(feed):
+    """Under a mesh the feed places every batch with the step's batch
+    sharding, and the state starts committed to the step's shardings, so
+    the step compiles once (uncommitted step-1 inputs compiled it twice)."""
+    from repro.launch.mesh import make_smoke_mesh, rules_for
+    cfg = get_smoke_config("qwen3-1.7b").replace(parallel_layout="fsdp")
+    mesh = make_smoke_mesh(1)
+    rt = TrainerRuntime(cfg, DataConfig(seq_len=32, global_batch=2),
+                        TrainerConfig(steps=3, feed=feed, log_every=1),
+                        mesh=mesh, rules=rules_for(mesh, "fsdp"))
+    compiles = []
+
+    def on_event(event, duration, **kw):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and "train_step" in str(kw.get("fun_name"))):
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        rt.run()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert len(compiles) == 1
+    assert rt._feed.stats.batches == 3
+    assert rt._feed.stats.devices == 1
