@@ -1,0 +1,55 @@
+"""Each simulator cell's traffic is the search it says it is: replaying its
+schedule of rates with ``engine="epoch"`` (which plans the same regime as
+the device engine, with numpy), the ramp-and-bisect rule applied to the
+trials' outcomes visits exactly those rates; every sustained trial stays
+on the epoch fast path and every trial that drops falls back."""
+import pytest
+
+from perfbench import spec
+from perfbench.drivers.l2fwd_sim import experiment_config
+from repro.core import EpochRunInfo
+from repro.exp import run_experiment
+
+B = spec.load()
+SIM_CELLS = [w["name"] for w in B["workloads"]
+             if spec.config(B, w)["driver"] == "l2fwd_sim"]
+
+
+def search_rates(sustains, start=0.1, refine=4, max_gbps=400.0):
+    """The rates a multiplicative ramp then ``refine`` bisection steps
+    visit, given ``sustains(rate) -> bool``."""
+    seen, good, rate = [], 0.0, start
+    while rate <= max_gbps:
+        seen.append(rate)
+        if not sustains(rate):
+            break
+        good, rate = rate, rate * 2
+    else:
+        return seen
+    lo, hi = rate / 2, rate
+    for _ in range(refine):
+        mid = (lo + hi) / 2
+        seen.append(mid)
+        lo, hi = (mid, hi) if sustains(mid) else (lo, mid)
+    return seen
+
+
+@pytest.mark.parametrize("cell", SIM_CELLS)
+def test_schedule_is_the_search_and_its_regime(cell):
+    wl = spec.workload(B, cell)
+    cfg = dict(spec.config(B, wl), engine="epoch")
+    traffic = spec.traffic(wl)
+    outcome = {}
+
+    def sustains(rate):
+        info = EpochRunInfo()
+        rep = run_experiment(experiment_config(cfg, traffic, rate, 0),
+                             info=info)
+        outcome[round(rate, 1)] = (rep.dropped == 0, info.engine)
+        return rep.dropped == 0 and rep.sent > 0
+
+    visited = [round(r, 1) for r in search_rates(sustains)]
+    assert visited == traffic["rates_gbps"]
+    assert all(engine == ("epoch" if ok else "event")
+               for ok, engine in outcome.values()), outcome
+    assert sum(not ok for ok, _e in outcome.values()) >= 1
