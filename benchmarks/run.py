@@ -41,8 +41,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     choices=["fig3a", "fig3b", "fig4", "incast", "aqm",
-                             "serving", "latency", "kernels", "roofline",
-                             "fastpath", "parallel"])
+                             "serving", "latency", "fastpath", "parallel"])
     # VIRTUAL seconds per MSB trial since the SimClock refactor: a few ms of
     # simulated traffic is statistically plenty and runs fast at any rate
     ap.add_argument("--trial-s", type=float, default=0.004)
@@ -76,7 +75,7 @@ def main() -> None:
 
     from . import (fastpath_bench, fig3a_scalability, fig3b_sensitivity,
                    fig4_dca_burst, fig_aqm, fig_incast, fig_serving,
-                   kernels_bench, parallel_bench, roofline, tbl_latency)
+                   parallel_bench, tbl_latency)
     from .common import ROWS
 
     sections: List[Section] = [
@@ -89,8 +88,6 @@ def main() -> None:
         ("serving", "csv",
          lambda: fig_serving.run(trial_s=min(args.trial_s, 0.002))),
         ("latency", "csv", tbl_latency.run),
-        ("kernels", "csv", kernels_bench.run),
-        ("roofline", "csv", roofline.run),
         ("fastpath", "text", lambda: fastpath_bench.run(quick=True)),
         ("parallel", "text",
          lambda: parallel_bench.run(quick=True, workers=args.workers)),

@@ -64,7 +64,7 @@ from ..kernels.epoch_fastpath import (epoch_pass_jax, epoch_pass_np,
 from .packet import DEFAULT_DST_IP, DEFAULT_SRC_IP_BASE, swap_macs_vec
 from .pmd import BypassL2FwdServer
 from .simclock import SimClock
-from .telemetry import RunReport
+from .telemetry import RunReport, span
 
 __all__ = ["EpochRunInfo", "EPOCH_FALLBACK_REASONS", "PARTITIONED_REASON",
            "run_epoch_sim", "iter_epoch_slices", "default_epoch_ns",
@@ -356,7 +356,8 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
     ``info.fallback_reason`` set) when a validation shows the run would
     leave the fast-path regime.  Mutates nothing."""
     rng = np.random.default_rng(pattern.seed)
-    times, sizes = pattern.emission_schedule(int(duration_s * 1e9), rng)
+    with span("repro.epoch.schedule"):
+        times, sizes = pattern.emission_schedule(int(duration_s * 1e9), rng)
     n = len(times)
     start = clock.now_ns
     info.n_packets = n
@@ -376,140 +377,151 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
     empty_i64 = np.empty(0, dtype=np.int64)
 
     # -- phase A: per-port wire pass + RSS split over epoch slices ----------
-    for pi, port in enumerate(ports):
-        e_p = times_abs[pi::nports]
-        orig_p = np.arange(pi, n, nports, dtype=np.int64)
-        sz_p = sizes[pi::nports]
-        gbps = float(getattr(port, "link_gbps", 0.0))
-        lat = int(getattr(port, "link_latency_ns", 0))
-        if len(e_p) == 0:
-            for qi in range(port.n_queues):
-                qplans[(pi, qi)] = _QueuePlan(pi, qi, port.rx_queues[qi],
-                                              empty_i64, empty_i64)
-            continue
-        ser_p = serialization_ns_vec(sz_p, gbps)
-        table = _flow_queue_table(port, lg.n_flows, lg.src_ip_base, lg.dst_ip)
-        fids = ((seq0 + orig_p) % lg.n_flows) if table is not None else None
-        busy = 0
-        arr_parts: List[np.ndarray] = []
-        q_parts: List[np.ndarray] = []
-        for lo, hi in iter_epoch_slices(e_p, epoch_ns):
-            a, busy, q = pass_fn(e_p[lo:hi], ser_p[lo:hi], busy, lat, table,
-                                 None if fids is None else fids[lo:hi])
-            arr_parts.append(np.asarray(a))
-            if q is not None:
-                q_parts.append(np.asarray(q))
-            info.n_epochs += 1
-        arr_p = np.concatenate(arr_parts)
-        if table is None:
-            qplans[(pi, 0)] = _QueuePlan(pi, 0, port.rx_queues[0], arr_p, orig_p)
-        else:
-            q_all = np.concatenate(q_parts)
-            for qi in range(port.n_queues):
-                mask = q_all == qi
-                qplans[(pi, qi)] = _QueuePlan(pi, qi, port.rx_queues[qi],
-                                              arr_p[mask], orig_p[mask])
+    with span("repro.epoch.wire"):
+        for pi, port in enumerate(ports):
+            e_p = times_abs[pi::nports]
+            orig_p = np.arange(pi, n, nports, dtype=np.int64)
+            sz_p = sizes[pi::nports]
+            gbps = float(getattr(port, "link_gbps", 0.0))
+            lat = int(getattr(port, "link_latency_ns", 0))
+            if len(e_p) == 0:
+                for qi in range(port.n_queues):
+                    qplans[(pi, qi)] = _QueuePlan(pi, qi, port.rx_queues[qi],
+                                                  empty_i64, empty_i64)
+                continue
+            ser_p = serialization_ns_vec(sz_p, gbps)
+            table = _flow_queue_table(port, lg.n_flows, lg.src_ip_base,
+                                      lg.dst_ip)
+            fids = (((seq0 + orig_p) % lg.n_flows) if table is not None
+                    else None)
+            busy = 0
+            arr_parts: List[np.ndarray] = []
+            q_parts: List[np.ndarray] = []
+            for lo, hi in iter_epoch_slices(e_p, epoch_ns):
+                with span("repro.epoch.pass"):
+                    a, busy, q = pass_fn(e_p[lo:hi], ser_p[lo:hi], busy, lat,
+                                         table,
+                                         None if fids is None else fids[lo:hi])
+                arr_parts.append(np.asarray(a))
+                if q is not None:
+                    q_parts.append(np.asarray(q))
+                info.n_epochs += 1
+            arr_p = np.concatenate(arr_parts)
+            if table is None:
+                qplans[(pi, 0)] = _QueuePlan(pi, 0, port.rx_queues[0],
+                                             arr_p, orig_p)
+            else:
+                q_all = np.concatenate(q_parts)
+                for qi in range(port.n_queues):
+                    mask = q_all == qi
+                    qplans[(pi, qi)] = _QueuePlan(pi, qi, port.rx_queues[qi],
+                                                  arr_p[mask], orig_p[mask])
 
     # -- phase B: per-lcore harvest cascade + terminal flush ----------------
-    cost_fn = server.sim_cost.pmd_burst_ns
-    lcore_free = list(server._lcore_next_free)
-    events: List[Tuple[int, _QueuePlan, int, int]] = []
-    for i, lc in enumerate(server.lcores):
-        group = [qplans[pr] for pr in lc.assignments]
-        lcore_free[i] = _cascade(group, lcore_free[i], lc.burst_size,
-                                 cost_fn, events)
-    a_last = max(int(qp.arr[-1]) for qp in qplans.values() if qp.n)
-    # the event loop's quiet-wire flush_rx fires once no emission, wire
-    # arrival, or future lcore-free candidate remains
-    t_flush = max([a_last] + lcore_free)
-    for qp in qplans.values():
-        qp.tail_time = t_flush
-    for i, lc in enumerate(server.lcores):
-        group = [qplans[pr] for pr in lc.assignments]
-        lcore_free[i] = _cascade(group, lcore_free[i], lc.burst_size,
-                                 cost_fn, events)
-    final_now = max([t_flush] + lcore_free)
+    with span("repro.epoch.cascade"):
+        cost_fn = server.sim_cost.pmd_burst_ns
+        lcore_free = list(server._lcore_next_free)
+        events: List[Tuple[int, _QueuePlan, int, int]] = []
+        for i, lc in enumerate(server.lcores):
+            group = [qplans[pr] for pr in lc.assignments]
+            lcore_free[i] = _cascade(group, lcore_free[i], lc.burst_size,
+                                     cost_fn, events)
+        a_last = max(int(qp.arr[-1]) for qp in qplans.values() if qp.n)
+        # the event loop's quiet-wire flush_rx fires once no emission, wire
+        # arrival, or future lcore-free candidate remains
+        t_flush = max([a_last] + lcore_free)
+        for qp in qplans.values():
+            qp.tail_time = t_flush
+        for i, lc in enumerate(server.lcores):
+            group = [qplans[pr] for pr in lc.assignments]
+            lcore_free[i] = _cascade(group, lcore_free[i], lc.burst_size,
+                                     cost_fn, events)
+        final_now = max([t_flush] + lcore_free)
 
     # -- validation 1: no RX ring ever fills --------------------------------
     # before accepting arrival j (0-indexed), in_flight is j minus harvests
     # strictly earlier (same-round harvests run after delivery); require the
     # post-accept occupancy j+1-hb to stay < size, which rules out both the
     # drop path and the full-triggered early writeback
-    for qp in qplans.values():
-        if qp.n == 0:
-            continue
-        ht = np.fromiter((t for t, _ in qp.harvests), dtype=np.int64,
-                         count=len(qp.harvests))
-        hc = np.cumsum(np.fromiter((h for _, h in qp.harvests),
-                                   dtype=np.int64, count=len(qp.harvests)))
-        idx = np.searchsorted(ht, qp.arr, side="left")
-        hb = np.where(idx > 0, hc[np.maximum(idx - 1, 0)], 0)
-        occ = np.arange(1, qp.n + 1, dtype=np.int64) - hb
-        if int(occ.max()) >= qp.ring.size:
-            info.fallback_reason = (
-                "RX ring would fill (overflow writeback/drop regime)")
-            return None
+    with span("repro.epoch.validate"):
+        for qp in qplans.values():
+            if qp.n == 0:
+                continue
+            ht = np.fromiter((t for t, _ in qp.harvests), dtype=np.int64,
+                             count=len(qp.harvests))
+            hc = np.cumsum(np.fromiter((h for _, h in qp.harvests),
+                                       dtype=np.int64, count=len(qp.harvests)))
+            idx = np.searchsorted(ht, qp.arr, side="left")
+            hb = np.where(idx > 0, hc[np.maximum(idx - 1, 0)], 0)
+            occ = np.arange(1, qp.n + 1, dtype=np.int64) - hb
+            if int(occ.max()) >= qp.ring.size:
+                info.fallback_reason = (
+                    "RX ring would fill (overflow writeback/drop regime)")
+                return None
 
-    # -- validation 2: the packet pool never exhausts -----------------------
-    # +1 at each emission, -1 at the harvest round that drains the frame
-    # (the event loop frees at drain time, not at return-wire arrival);
-    # same-time allocs precede frees (loop step order: emit ... drain)
-    free_t = np.empty(n, dtype=np.int64)
-    for t, qp, s, h in events:
-        free_t[qp.orig[s:s + h]] = t
-    pool_ports: Dict[int, Tuple[object, List[int]]] = {}
-    for pi, port in enumerate(ports):
-        pool_ports.setdefault(id(port.pool), (port.pool, []))[1].append(pi)
-    for pool, pis in pool_ports.values():
-        alloc_t = np.concatenate([times_abs[pi::nports] for pi in pis])
-        freed_t = np.concatenate([free_t[pi::nports] for pi in pis])
-        if len(alloc_t) == 0:
-            continue
-        ev_t = np.concatenate([alloc_t, freed_t])
-        delta = np.concatenate([np.ones(len(alloc_t), dtype=np.int64),
-                                -np.ones(len(freed_t), dtype=np.int64)])
-        kind = np.concatenate([np.zeros(len(alloc_t), dtype=np.int8),
-                               np.ones(len(freed_t), dtype=np.int8)])
-        order = np.lexsort((kind, ev_t))
-        occ = np.cumsum(delta[order])
-        if int(occ.max()) > pool.n_free:
-            info.fallback_reason = "packet pool would exhaust"
-            return None
+        # -- validation 2: the packet pool never exhausts -------------------
+        # +1 at each emission, -1 at the harvest round that drains the frame
+        # (the event loop frees at drain time, not at return-wire arrival);
+        # same-time allocs precede frees (loop step order: emit ... drain)
+        free_t = np.empty(n, dtype=np.int64)
+        for t, qp, s, h in events:
+            free_t[qp.orig[s:s + h]] = t
+        pool_ports: Dict[int, Tuple[object, List[int]]] = {}
+        for pi, port in enumerate(ports):
+            pool_ports.setdefault(id(port.pool), (port.pool, []))[1].append(pi)
+        for pool, pis in pool_ports.values():
+            alloc_t = np.concatenate([times_abs[pi::nports] for pi in pis])
+            freed_t = np.concatenate([free_t[pi::nports] for pi in pis])
+            if len(alloc_t) == 0:
+                continue
+            ev_t = np.concatenate([alloc_t, freed_t])
+            delta = np.concatenate([np.ones(len(alloc_t), dtype=np.int64),
+                                    -np.ones(len(freed_t), dtype=np.int64)])
+            kind = np.concatenate([np.zeros(len(alloc_t), dtype=np.int8),
+                                   np.ones(len(freed_t), dtype=np.int8)])
+            order = np.lexsort((kind, ev_t))
+            occ = np.cumsum(delta[order])
+            if int(occ.max()) > pool.n_free:
+                info.fallback_reason = "packet pool would exhaust"
+                return None
 
     # -- phase C: TX drains through the return wires ------------------------
     # drains happen in the same round as their harvest; per round the event
     # loop drains ports in order and queues in order within a port, and the
     # RTT sample order must match exactly (mean/std are order-sensitive)
-    ev_by_port: Dict[int, List[Tuple[int, int, _QueuePlan, int, int]]] = {}
-    for t, qp, s, h in events:
-        ev_by_port.setdefault(qp.pi, []).append((t, qp.qi, qp, s, h))
-    tagged: List[Tuple[int, int, int, np.ndarray]] = []
-    meter_bytes = 0
-    meter_start: Optional[int] = None
-    meter_end: Optional[int] = None
-    for pi, evs in ev_by_port.items():
-        evs.sort(key=lambda e: (e[0], e[1]))
-        handed = np.concatenate(
-            [np.full(h, t, dtype=np.int64) for t, _qi, _qp, _s, h in evs])
-        origs = np.concatenate([qp.orig[s:s + h] for _t, _qi, qp, s, h in evs])
-        lens = sizes[origs]
-        port = ports[pi]
-        gbps = float(getattr(port, "link_gbps", 0.0))
-        lat = int(getattr(port, "link_latency_ns", 0))
-        ser_b = serialization_ns_vec(lens, gbps)
-        arr_b, _ = wire_arrival_pass_np(handed, ser_b, 0, lat)
-        rtts_p = np.maximum(0, arr_b - times_abs[origs])
-        meter_bytes += int(lens.sum())
-        ms, me = int(arr_b[0]), int(arr_b[-1])  # FIFO: endpoints are min/max
-        meter_start = ms if meter_start is None else min(meter_start, ms)
-        meter_end = me if meter_end is None else max(meter_end, me)
-        off = 0
-        for t, qi, _qp, _s, h in evs:
-            tagged.append((t, pi, qi, rtts_p[off:off + h]))
-            off += h
-    tagged.sort(key=lambda e: (e[0], e[1], e[2]))
-    rtts = (np.concatenate([e[3] for e in tagged]) if tagged
-            else np.empty(0, dtype=np.int64))
+    with span("repro.epoch.drain"):
+        ev_by_port: Dict[int, List[Tuple[int, int, _QueuePlan, int, int]]] = {}
+        for t, qp, s, h in events:
+            ev_by_port.setdefault(qp.pi, []).append((t, qp.qi, qp, s, h))
+        tagged: List[Tuple[int, int, int, np.ndarray]] = []
+        meter_bytes = 0
+        meter_start: Optional[int] = None
+        meter_end: Optional[int] = None
+        for pi, evs in ev_by_port.items():
+            evs.sort(key=lambda e: (e[0], e[1]))
+            handed = np.concatenate(
+                [np.full(h, t, dtype=np.int64) for t, _qi, _qp, _s, h in evs])
+            origs = np.concatenate(
+                [qp.orig[s:s + h] for _t, _qi, qp, s, h in evs])
+            lens = sizes[origs]
+            port = ports[pi]
+            gbps = float(getattr(port, "link_gbps", 0.0))
+            lat = int(getattr(port, "link_latency_ns", 0))
+            ser_b = serialization_ns_vec(lens, gbps)
+            arr_b, _ = wire_arrival_pass_np(handed, ser_b, 0, lat)
+            rtts_p = np.maximum(0, arr_b - times_abs[origs])
+            meter_bytes += int(lens.sum())
+            # FIFO: the endpoints are the min and the max
+            ms, me = int(arr_b[0]), int(arr_b[-1])
+            meter_start = ms if meter_start is None else min(meter_start, ms)
+            meter_end = me if meter_end is None else max(meter_end, me)
+            off = 0
+            for t, qi, _qp, _s, h in evs:
+                tagged.append((t, pi, qi, rtts_p[off:off + h]))
+                off += h
+        tagged.sort(key=lambda e: (e[0], e[1], e[2]))
+        rtts = (np.concatenate([e[3] for e in tagged]) if tagged
+                else np.empty(0, dtype=np.int64))
 
     return _Plan(n=n, start=start, open_window_at=int(times_abs[0]),
                  sizes=sizes, qplans=list(qplans.values()),
@@ -603,8 +615,9 @@ def run_epoch_sim(loadgen, server, pattern, duration_s: float = 0.25,
         if reason is not None:
             info.fallback_reason = reason
         else:
-            plan = _build_plan(loadgen, server, pattern, clock, duration_s,
-                               epoch_ns, use_jax, info)
+            with span("repro.epoch.plan"):
+                plan = _build_plan(loadgen, server, pattern, clock,
+                                   duration_s, epoch_ns, use_jax, info)
     except Exception as exc:  # numpy planning is pure — safe to fall back
         if use_jax:
             raise
@@ -619,4 +632,5 @@ def run_epoch_sim(loadgen, server, pattern, duration_s: float = 0.25,
                                sched=sched)
     info.engine = "epoch-jit" if use_jax else "epoch"
     info.fastpath = True
-    return _commit(loadgen, server, pattern, clock, plan)
+    with span("repro.epoch.commit"):
+        return _commit(loadgen, server, pattern, clock, plan)

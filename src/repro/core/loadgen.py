@@ -67,7 +67,7 @@ from .packet import (
 from .pmd import Port
 from .simclock import EventScheduler, SimClock, Wire
 from .telemetry import (LatencyRecorder, RunReport, ThroughputMeter, rss_skew,
-                        writeback_extras)
+                        span, writeback_extras)
 
 TRAFFIC_KINDS = ("uniform", "poisson", "bursty")
 
@@ -677,105 +677,113 @@ class LoadGen:
         next_free = getattr(server, "next_free_ns", None)
         i, n = 0, len(times)
         flushed_idle = False
-        for _ in range(max_rounds):
-            now = clock.now_ns
-            moved = 0
-            # 1) emissions due: stamp with the *scheduled* time and put the
-            #    frame on its port's forward wire
-            while i < n and times[i] <= now:
-                t_emit = int(times[i])
-                size = int(sizes[i])
-                port = self.ports[i % nports]
-                slot = port.pool.alloc()
-                self.flight.sent += 1
-                if slot is not None:
-                    self._write_frame(port.pool, slot, size, t_emit,
-                                      rng if use_rng_payload else None)
-                    arrival = fwd[i % nports].transmit(t_emit, size)
-                    on_wire[i % nports].append((arrival, slot, size))
-                else:
-                    # out of buffers: the emission still counts as offered
-                    # load, but attribute the vanished frame explicitly
-                    self.flight.alloc_failures += 1
-                i += 1
-                moved += 1
-            # 1b) rate-adaptive emissions: same body, but the next emission
-            #     time is minted per frame from the controller's current rate
-            while cc_next is not None and int(cc_next) <= now:
-                t_emit = int(cc_next)
-                size = pattern.packet_size
-                # a tick finding the in-flight cap exhausted is forfeited
-                # (paced probing); the cursor still advances
-                if cc.can_send():
+        rounds = -1
+        with span("repro.loadgen.event_loop") as loop:
+            for rounds in range(max_rounds):
+                now = clock.now_ns
+                moved = 0
+                # 1) emissions due: stamp with the *scheduled* time and put
+                #    the frame on its port's forward wire
+                while i < n and times[i] <= now:
+                    t_emit = int(times[i])
+                    size = int(sizes[i])
                     port = self.ports[i % nports]
                     slot = port.pool.alloc()
                     self.flight.sent += 1
-                    cc.on_send(t_emit)
                     if slot is not None:
                         self._write_frame(port.pool, slot, size, t_emit,
                                           rng if use_rng_payload else None)
                         arrival = fwd[i % nports].transmit(t_emit, size)
                         on_wire[i % nports].append((arrival, slot, size))
                     else:
+                        # out of buffers: the emission still counts as
+                        # offered load, but attribute the vanished frame
+                        # explicitly
                         self.flight.alloc_failures += 1
                     i += 1
-                moved += 1
-                cc_next += cc.gap_ns(size)
-                if cc_next >= cc_end:
-                    cc_next = None
-            # 2) wire arrivals due: NIC-side delivery (RSS steering; ring
-            #    overflow drops here, exactly like hardware)
-            for pi, dq in enumerate(on_wire):
-                port = self.ports[pi]
-                while dq and dq[0][0] <= now:
-                    _, slot, size = dq.popleft()
-                    port.deliver(slot, size)
                     moved += 1
-            # 2b) scheduler events due: descriptor-cache writeback timeouts
-            #     fire after deliveries at `now` (a threshold crossing at the
-            #     same instant cancels the timer first), before the PMD polls
-            if sched is not None:
-                moved += sched.run_until(now)
-            # 3) one server scheduling round at virtual `now`
-            if poll_at is not None:
-                moved += poll_at(now)
-            else:
-                moved += server.poll_once()
-            # 4) wire-side TX drain; RTT recorded at return-link arrival
-            for pi, port in enumerate(self.ports):
-                moved += self._drain_port(port, now, back_wire=back[pi])
-            # 5) advance to the next event
-            cands = []
-            if i < n:
-                cands.append(int(times[i]))
-            if cc_next is not None:
-                cands.append(int(cc_next))
-            for dq in on_wire:
-                if dq:
-                    cands.append(dq[0][0])
-            if next_free is not None:
-                nf = next_free(now)
-                if nf is not None:
-                    cands.append(nf)
-            if sched is not None:
-                nt = sched.next_time_ns()
-                if nt is not None:
-                    cands.append(nt)
-            if cands:
-                flushed_idle = False
-                clock.advance_to(min(cands))
-                continue
-            if moved > 0:
-                flushed_idle = False
-                continue
-            if not flushed_idle:
-                # quiet wire: the NIC's timeout-driven descriptor-cache
-                # writeback fires, releasing sub-threshold completions
-                for port in self.ports:
-                    port.flush_rx()
-                flushed_idle = True
-                continue
-            break  # nothing scheduled, nothing moving: remaining == drops
+                # 1b) rate-adaptive emissions: same body, but the next
+                #     emission time is minted per frame from the
+                #     controller's current rate
+                while cc_next is not None and int(cc_next) <= now:
+                    t_emit = int(cc_next)
+                    size = pattern.packet_size
+                    # a tick finding the in-flight cap exhausted is forfeited
+                    # (paced probing); the cursor still advances
+                    if cc.can_send():
+                        port = self.ports[i % nports]
+                        slot = port.pool.alloc()
+                        self.flight.sent += 1
+                        cc.on_send(t_emit)
+                        if slot is not None:
+                            self._write_frame(
+                                port.pool, slot, size, t_emit,
+                                rng if use_rng_payload else None)
+                            arrival = fwd[i % nports].transmit(t_emit, size)
+                            on_wire[i % nports].append((arrival, slot, size))
+                        else:
+                            self.flight.alloc_failures += 1
+                        i += 1
+                    moved += 1
+                    cc_next += cc.gap_ns(size)
+                    if cc_next >= cc_end:
+                        cc_next = None
+                # 2) wire arrivals due: NIC-side delivery (RSS steering; ring
+                #    overflow drops here, exactly like hardware)
+                for pi, dq in enumerate(on_wire):
+                    port = self.ports[pi]
+                    while dq and dq[0][0] <= now:
+                        _, slot, size = dq.popleft()
+                        port.deliver(slot, size)
+                        moved += 1
+                # 2b) scheduler events due: descriptor-cache writeback
+                #     timeouts fire after deliveries at `now` (a threshold
+                #     crossing at the same instant cancels the timer first),
+                #     before the PMD polls
+                if sched is not None:
+                    moved += sched.run_until(now)
+                # 3) one server scheduling round at virtual `now`
+                if poll_at is not None:
+                    moved += poll_at(now)
+                else:
+                    moved += server.poll_once()
+                # 4) wire-side TX drain; RTT recorded at return-link arrival
+                for pi, port in enumerate(self.ports):
+                    moved += self._drain_port(port, now, back_wire=back[pi])
+                # 5) advance to the next event
+                cands = []
+                if i < n:
+                    cands.append(int(times[i]))
+                if cc_next is not None:
+                    cands.append(int(cc_next))
+                for dq in on_wire:
+                    if dq:
+                        cands.append(dq[0][0])
+                if next_free is not None:
+                    nf = next_free(now)
+                    if nf is not None:
+                        cands.append(nf)
+                if sched is not None:
+                    nt = sched.next_time_ns()
+                    if nt is not None:
+                        cands.append(nt)
+                if cands:
+                    flushed_idle = False
+                    clock.advance_to(min(cands))
+                    continue
+                if moved > 0:
+                    flushed_idle = False
+                    continue
+                if not flushed_idle:
+                    # quiet wire: the NIC's timeout-driven descriptor-cache
+                    # writeback fires, releasing sub-threshold completions
+                    for port in self.ports:
+                        port.flush_rx()
+                    flushed_idle = True
+                    continue
+                # nothing scheduled, nothing moving: remaining == drops
+                break
+            loop.set_metadata(rounds=rounds + 1)
         rep = self._report(
             offered_gbps=pattern.rate_gbps if pattern.trace is None else 0.0)
         rep.extras["sim_time"] = 1.0
@@ -847,47 +855,53 @@ class LoadGen:
             offered_gbps=pattern.rate_gbps if pattern.trace is None else 0.0)
 
     def _report(self, offered_gbps: float) -> RunReport:
-        rep = RunReport(
-            offered_gbps=offered_gbps,
-            achieved_gbps=self.meter.gbps,
-            achieved_mpps=self.meter.mpps,
-            sent=self.flight.sent,
-            received=self.flight.received,
-            dropped=self.flight.sent - self.flight.received,
-            latency=self.latency.stats(),
-            histogram=self.latency.histogram(),
-        )
-        rep.extras["integrity_errors"] = float(self.flight.integrity_errors)
-        # generator buffer starvation (offered load that never hit a wire)
-        rep.extras["loadgen_alloc_failures"] = float(self.flight.alloc_failures)
-        # ECN / congestion-control telemetry, only when the fabric actually
-        # marked something or a controller is attached (keeps pre-AQM
-        # reports byte-identical)
-        if self.flight.ce_marked or self.cc is not None:
-            rep.extras["ce_marked"] = float(self.flight.ce_marked)
-        if self.cc is not None:
-            rep.extras["cc_windows"] = float(self.cc.windows)
-            rep.extras["cc_final_rate_gbps"] = self.cc.rate_gbps
-            rep.extras["cc_min_rate_gbps"] = self.cc.rate_min
-            rep.extras["cc_max_rate_gbps"] = self.cc.rate_max
-            rep.extras["cc_alpha"] = self.cc.alpha
-            rep.extras["cc_lost_inferred"] = float(self.cc.lost_accounted)
-        # per-RX-ring descriptor-writeback telemetry (the Fig. 4 observable)
-        rep.extras.update(writeback_extras(self.ports))
-        # per-queue NIC-side accounting (the RSS-skew observable); only
-        # reported for multi-queue ports to keep single-queue reports terse
-        for pi, port in enumerate(self.ports):
-            if port.n_queues <= 1:
-                continue
-            delivered = port.rx_queue_delivered()
-            dropped = port.rx_queue_dropped()
-            for qi in range(port.n_queues):
-                rep.extras[f"p{pi}q{qi}_rx_delivered"] = float(delivered[qi])
-                rep.extras[f"p{pi}q{qi}_rx_dropped"] = float(dropped[qi])
-            skew = rss_skew(delivered)
-            rep.extras[f"p{pi}_rss_imbalance"] = skew["max_over_mean"]
-            rep.extras[f"p{pi}_rss_cov"] = skew["cov"]
-        return rep
+        with span("repro.report"):
+            rep = RunReport(
+                offered_gbps=offered_gbps,
+                achieved_gbps=self.meter.gbps,
+                achieved_mpps=self.meter.mpps,
+                sent=self.flight.sent,
+                received=self.flight.received,
+                dropped=self.flight.sent - self.flight.received,
+                latency=self.latency.stats(),
+                histogram=self.latency.histogram(),
+            )
+            rep.extras["integrity_errors"] = float(
+                self.flight.integrity_errors)
+            # generator buffer starvation (offered load that never hit a wire)
+            rep.extras["loadgen_alloc_failures"] = float(
+                self.flight.alloc_failures)
+            # ECN / congestion-control telemetry, only when the fabric
+            # actually marked something or a controller is attached (keeps
+            # pre-AQM reports byte-identical)
+            if self.flight.ce_marked or self.cc is not None:
+                rep.extras["ce_marked"] = float(self.flight.ce_marked)
+            if self.cc is not None:
+                rep.extras["cc_windows"] = float(self.cc.windows)
+                rep.extras["cc_final_rate_gbps"] = self.cc.rate_gbps
+                rep.extras["cc_min_rate_gbps"] = self.cc.rate_min
+                rep.extras["cc_max_rate_gbps"] = self.cc.rate_max
+                rep.extras["cc_alpha"] = self.cc.alpha
+                rep.extras["cc_lost_inferred"] = float(self.cc.lost_accounted)
+            # per-RX-ring descriptor-writeback telemetry (the Fig. 4
+            # observable)
+            rep.extras.update(writeback_extras(self.ports))
+            # per-queue NIC-side accounting (the RSS-skew observable); only
+            # reported for multi-queue ports to keep single-queue reports
+            # terse
+            for pi, port in enumerate(self.ports):
+                if port.n_queues <= 1:
+                    continue
+                delivered = port.rx_queue_delivered()
+                dropped = port.rx_queue_dropped()
+                for qi in range(port.n_queues):
+                    rep.extras[f"p{pi}q{qi}_rx_delivered"] = float(
+                        delivered[qi])
+                    rep.extras[f"p{pi}q{qi}_rx_dropped"] = float(dropped[qi])
+                skew = rss_skew(delivered)
+                rep.extras[f"p{pi}_rss_imbalance"] = skew["max_over_mean"]
+                rep.extras[f"p{pi}_rss_cov"] = skew["cov"]
+            return rep
 
 
 # -- bandwidth test mode ------------------------------------------------------

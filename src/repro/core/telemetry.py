@@ -4,14 +4,44 @@ This is the measurement half of EtherLoadGen (paper §3.3): "reports mean,
 median, standard deviation, and tail latency of network packets ... also
 produces a packet drop percentage and a histogram of packet forwarding
 latency."
+
+It also holds :func:`span`, the host spans that name the program's own work
+in a ``jax.profiler`` trace.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+class _NoSpan:
+    """What :func:`span` gives while no profiler session runs."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **kwargs) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str) -> Union[TraceAnnotation, _NoSpan]:
+    """A host span ``name`` in the running ``jax.profiler`` session, on the
+    clock of the device trace; with no session running, one shared no-op.
+    ``set_metadata(key=value)`` on it adds arguments to the span.  Spans
+    touch no simulated state: a ``RunReport`` is the same traced or not."""
+    return TraceAnnotation(name) if TraceAnnotation.is_enabled() else _NO_SPAN
 
 
 @dataclass

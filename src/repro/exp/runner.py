@@ -19,6 +19,7 @@ import numpy as np
 from repro.core import (EpochRunInfo, EthDev, NetworkStack, PARTITIONED_REASON,
                         PartitionRunInfo, RunReport, TrafficPattern,
                         find_max_sustainable_bandwidth, run_epoch_sim)
+from repro.core.telemetry import span
 
 from .config import ExperimentConfig, TopologyConfig
 from .testbed import Testbed
@@ -79,28 +80,29 @@ def run_experiment(cfg: ExperimentConfig, *,
                    info: Optional[EpochRunInfo] = None) -> RunReport:
     """Build + run one experiment from config alone (``info``: see
     :func:`run_testbed`)."""
-    t = cfg.traffic
-    if t.mode in ("closed_loop", "open_loop"):
-        return run_testbed(Testbed.build(cfg), info=info)
-    # msb: ramp + bisect over fresh testbeds
-    gbps, reports = find_max_sustainable_bandwidth(
-        make_server_factory(cfg),
-        packet_size=t.packet_size,
-        start_gbps=t.start_gbps,
-        max_gbps=t.max_gbps,
-        trial_s=t.trial_s,
-        drop_tolerance_pct=t.drop_tolerance_pct,
-        refine_iters=t.refine_iters,
-        pattern_kind=t.kind,
-        sim_time=t.sim_time,
-        engine=t.engine,
-    )
-    good = [r for r in reports
-            if r.drop_pct <= t.drop_tolerance_pct and r.received > 0]
-    rep = max(good, key=lambda r: r.achieved_gbps) if good else RunReport()
-    rep.extras["msb_gbps"] = gbps
-    rep.extras["msb_trials"] = float(len(reports))
-    return rep
+    with span("repro.experiment"):
+        t = cfg.traffic
+        if t.mode in ("closed_loop", "open_loop"):
+            return run_testbed(Testbed.build(cfg), info=info)
+        # msb: ramp + bisect over fresh testbeds
+        gbps, reports = find_max_sustainable_bandwidth(
+            make_server_factory(cfg),
+            packet_size=t.packet_size,
+            start_gbps=t.start_gbps,
+            max_gbps=t.max_gbps,
+            trial_s=t.trial_s,
+            drop_tolerance_pct=t.drop_tolerance_pct,
+            refine_iters=t.refine_iters,
+            pattern_kind=t.kind,
+            sim_time=t.sim_time,
+            engine=t.engine,
+        )
+        good = [r for r in reports
+                if r.drop_pct <= t.drop_tolerance_pct and r.received > 0]
+        rep = max(good, key=lambda r: r.achieved_gbps) if good else RunReport()
+        rep.extras["msb_gbps"] = gbps
+        rep.extras["msb_trials"] = float(len(reports))
+        return rep
 
 
 def run_topology_experiment(cfg: TopologyConfig, *,

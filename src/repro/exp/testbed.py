@@ -15,6 +15,7 @@ from repro.core import (BurstPlan, BypassL2FwdServer, EthConf, EthDev,
                         EventScheduler, KernelStackServer, LoadGen,
                         NetworkStack, PacketPool, PipelineServer,
                         QueueTelemetry, SimClock)
+from repro.core.telemetry import span
 
 from .config import CostConfig, DcaConfig, ExperimentConfig, StackConfig
 
@@ -131,38 +132,45 @@ class Testbed:
 
     @classmethod
     def build(cls, cfg: ExperimentConfig) -> "Testbed":
-        pool = PacketPool(cfg.pool.n_slots, cfg.pool.slot_size)
-        devs: List[EthDev] = []
-        for dev_id, pc in enumerate(cfg.ports):
-            dev = EthDev(pool, dev_id=dev_id).configure(EthConf(
-                n_rx_queues=pc.n_queues, n_tx_queues=pc.n_queues,
-                rss_key=pc.rss.key, rss_table_size=pc.rss.table_size,
-                link_gbps=pc.link.gbps, link_latency_ns=pc.link.latency_ns))
-            for q in range(pc.n_queues):
-                thr = effective_writeback_threshold(
-                    cfg.dca, pc.writeback_threshold, q)
-                dev.rx_queue_setup(q, pc.ring_size, writeback_threshold=thr)
-                dev.tx_queue_setup(q, pc.ring_size)
-            devs.append(dev.dev_start())
-        server = build_stack(effective_stack_config(cfg.stack, cfg.dca), devs)
-        clock: Optional[SimClock] = None
-        sched: Optional[EventScheduler] = None
-        if cfg.traffic.sim_time:
-            # one virtual clock per testbed: the loadgen advances it, the
-            # server charges lcore busy-time against it, and one event queue
-            # on that clock carries NIC-side timers
-            clock = SimClock()
-            sched = EventScheduler(clock)
-            if hasattr(server, "attach_clock"):
-                cost = (cfg.stack.cost if cfg.stack.cost is not None
-                        else CostConfig())
-                server.attach_clock(clock, cost.to_host_cost_model())
-            apply_dca(cfg.dca, devs, server, sched)
-        t = cfg.traffic
-        loadgen = LoadGen(devs, ts_offset=t.ts_offset,
-                          verify_integrity=t.verify_integrity,
-                          max_tx_burst=t.max_tx_burst, n_flows=t.n_flows)
-        return cls(cfg, pool, devs, server, loadgen, clock=clock, sched=sched)
+        with span("repro.testbed.build"):
+            with span("repro.testbed.pool"):
+                pool = PacketPool(cfg.pool.n_slots, cfg.pool.slot_size)
+            devs: List[EthDev] = []
+            for dev_id, pc in enumerate(cfg.ports):
+                with span("repro.testbed.port"):
+                    dev = EthDev(pool, dev_id=dev_id).configure(EthConf(
+                        n_rx_queues=pc.n_queues, n_tx_queues=pc.n_queues,
+                        rss_key=pc.rss.key, rss_table_size=pc.rss.table_size,
+                        link_gbps=pc.link.gbps,
+                        link_latency_ns=pc.link.latency_ns))
+                    for q in range(pc.n_queues):
+                        thr = effective_writeback_threshold(
+                            cfg.dca, pc.writeback_threshold, q)
+                        dev.rx_queue_setup(q, pc.ring_size,
+                                           writeback_threshold=thr)
+                        dev.tx_queue_setup(q, pc.ring_size)
+                    devs.append(dev.dev_start())
+            server = build_stack(effective_stack_config(cfg.stack, cfg.dca),
+                                 devs)
+            clock: Optional[SimClock] = None
+            sched: Optional[EventScheduler] = None
+            if cfg.traffic.sim_time:
+                # one virtual clock per testbed: the loadgen advances it,
+                # the server charges lcore busy-time against it, and one
+                # event queue on that clock carries NIC-side timers
+                clock = SimClock()
+                sched = EventScheduler(clock)
+                if hasattr(server, "attach_clock"):
+                    cost = (cfg.stack.cost if cfg.stack.cost is not None
+                            else CostConfig())
+                    server.attach_clock(clock, cost.to_host_cost_model())
+                apply_dca(cfg.dca, devs, server, sched)
+            t = cfg.traffic
+            loadgen = LoadGen(devs, ts_offset=t.ts_offset,
+                              verify_integrity=t.verify_integrity,
+                              max_tx_burst=t.max_tx_burst, n_flows=t.n_flows)
+            return cls(cfg, pool, devs, server, loadgen, clock=clock,
+                       sched=sched)
 
     def xstats(self) -> Dict[str, int]:
         """Merged extended stats over every device, DPDK-named with a
