@@ -15,10 +15,12 @@ of the analytic emission schedule as whole-array passes
 * **steer**: RSS queue choice as a gather through a per-flow-id queue table
   (the loadgen's synthetic flow tuples cycle mod ``n_flows``, so the
   Toeplitz hash + indirection lookup is hoisted out of the per-packet path);
-* **writeback**: with no ring-full event, descriptor publishes are
-  poll-independent — the k-th writeback of a queue happens exactly when its
-  ``k*W``-th frame arrives (threshold ``W``), so publish times are a strided
-  slice of the arrival array;
+* **writeback/drop**: between two harvests of a queue its tail stands
+  still, so the next publish is the arrival that completes a threshold
+  batch (``W`` frames from the last writeback) or the one that fills the
+  ring (an early writeback of the whole cache), whichever comes first; a
+  full ring drops every arrival up to the next harvest instant — index
+  arithmetic on the arrival array, clipped by the ring's occupancy;
 * **harvest/charge**: each lcore's service history is a short burst-level
   cascade — ``t = max(lcore_free, earliest publish)``, harvest
   ``min(burst, backlog)`` per assigned queue in order, accumulate
@@ -33,11 +35,10 @@ of the analytic emission schedule as whole-array passes
   (latency stats such as ``np.mean`` are float-order-sensitive).
 
 **Exactness contract**: the engine plans the whole run *purely* (no state
-mutated), validates that the run stays inside the fast-path regime — no RX
-ring ever fills (no drops, no full-triggered writeback), the packet pool
-never exhausts, no writeback-timeout timers, no DCA accumulate mode, default
-burst transform — and only then commits counters, latency samples, meter
-windows, lcore busy times, and the final clock in one step.  Any unsupported
+mutated), validates that the run stays inside the fast-path regime — the
+packet pool never exhausts, no writeback-timeout timers, no DCA accumulate
+mode, default burst transform — and only then commits counters, latency
+samples, meter windows, lcore busy times, and the final clock in one step.  Any unsupported
 configuration or validation failure falls back to ``loadgen.run_sim`` before
 anything is touched, so **RunReports are bit-identical to the event loop in
 every case** — either computed by the closed forms proven equivalent, or by
@@ -99,7 +100,6 @@ EPOCH_FALLBACK_REASONS: Tuple[str, ...] = (
     "TX ring not idle",
     "lcore burst exceeds loadgen max_tx_burst (TX would linger)",
     "lcore burst exceeds TX ring size",
-    "RX ring would fill (overflow writeback/drop regime)",
     "packet pool would exhaust",
     PARTITIONED_REASON,
 )
@@ -180,6 +180,7 @@ class EpochRunInfo:
     used_jax: bool = False
     n_epochs: int = 0
     n_packets: int = 0
+    n_dropped: int = 0      # frames the committed plan dropped at full rings
 
     def __setattr__(self, name: str, value) -> None:
         # dataclass __init__ assigns via setattr, so construction-time
@@ -190,10 +191,18 @@ class EpochRunInfo:
 
 
 class _QueuePlan:
-    """Planned per-(port, queue) arrival stream + harvest history."""
+    """Planned per-(port, queue) arrival stream, RX ring and harvest history.
 
-    __slots__ = ("pi", "qi", "ring", "arr", "orig", "n", "W", "n_full",
-                 "batch_times", "pos", "wb_ptr", "tail_time", "harvests")
+    The ring is replayed lazily: with the tail fixed at ``pos``, the next
+    writeback comes when the accepted count reaches ``pub + W`` (threshold)
+    or ``pos + size`` (the ring fills), whichever is first, and the frame
+    that brings the accepted count to ``k`` is arrival ``k + n_drop - 1``
+    (every drop so far precedes it: a ring drops only while full, and the
+    harvest that ends a full spell settles its drops first)."""
+
+    __slots__ = ("pi", "qi", "ring", "arr", "orig", "n", "W", "size", "pub",
+                 "pos", "n_drop", "drops", "acc", "wb_sizes", "seen_t",
+                 "tail_time", "harvests")
 
     def __init__(self, pi: int, qi: int, ring, arr: np.ndarray,
                  orig: np.ndarray):
@@ -202,31 +211,78 @@ class _QueuePlan:
         self.orig = orig    # global emission indices, arrival order
         self.n = len(arr)
         thr = ring.writeback_threshold
-        self.W = ring.size if thr is None else int(thr)
-        self.n_full = self.n // self.W
-        # the k-th threshold writeback publishes when frame (k+1)*W-1 lands
-        self.batch_times = arr[self.W - 1::self.W][:self.n_full]
+        self.size = ring.size
+        self.W = self.size if thr is None else int(thr)
+        self.pub = 0         # descriptors written back (PMD-visible or taken)
         self.pos = 0         # descriptors harvested so far (the PMD tail)
-        self.wb_ptr = 0      # full batches published by current cascade time
+        self.n_drop = 0      # arrivals dropped at a full ring so far
+        self.drops: List[Tuple[int, int]] = []  # dropped arrival index ranges
+        self.acc = orig      # emission indices of accepted frames (see accept)
+        self.wb_sizes: List[int] = []  # every writeback's size, in order
+        self.seen_t = 0      # the time the ring was last settled to
         self.tail_time: Optional[int] = None  # T_flush once the tail phase runs
         self.harvests: List[Tuple[int, int]] = []  # [(t, n)], time order
 
     def next_pub_time(self) -> Optional[int]:
-        """When the first not-yet-harvested descriptor becomes PMD-visible."""
-        if self.pos < self.n_full * self.W:
-            return int(self.batch_times[self.pos // self.W])
-        if self.tail_time is not None and self.pos < self.n:
+        """When the first not-yet-harvested descriptor becomes PMD-visible,
+        if the tail stays where it is."""
+        if self.pub > self.pos:
+            return self.seen_t   # visible since the last settle
+        # with pub == pos the threshold comes first (W <= size)
+        j = self.pub + self.W + self.n_drop - 1
+        if j < self.n:
+            return int(self.arr[j])
+        if self.tail_time is not None and self.pub + self.n_drop < self.n:
             return self.tail_time
         return None
 
     def published_at(self, t: int) -> int:
-        """Total descriptors written back at time <= t (t must be
-        non-decreasing across calls — it is, per lcore)."""
-        while self.wb_ptr < self.n_full and self.batch_times[self.wb_ptr] <= t:
-            self.wb_ptr += 1
+        """Settle the ring through time ``t`` with the tail at ``pos``:
+        deliver every arrival at or before ``t`` (deliveries precede the
+        poll within a round), record the writebacks and drops they cause,
+        and return the descriptors written back.  ``t`` must be
+        non-decreasing across calls — it is, per lcore."""
+        arr, n, W = self.arr, self.n, self.W
+        pub, full = self.pub, self.pos + self.size
+        while pub < full:
+            acc = pub + W
+            if acc > full:
+                acc = full   # the ring fills first: a partial writeback
+            j = acc + self.n_drop - 1
+            if j >= n or arr[j] > t:
+                break
+            self.wb_sizes.append(acc - pub)
+            pub = acc
+        else:
+            # full ring: everything up to the harvest at t drops
+            lo = pub + self.n_drop
+            if lo < n and arr[lo] <= t:
+                hi = int(np.searchsorted(arr, t, side="right"))
+                self.drops.append((lo, hi))
+                self.n_drop += hi - lo
         if self.tail_time is not None and t >= self.tail_time:
-            return self.n
-        return self.wb_ptr * self.W
+            rest = n - self.n_drop - pub   # the quiet-wire flush
+            if rest > 0:
+                self.wb_sizes.append(rest)
+                pub += rest
+        self.pub = pub
+        self.seen_t = t
+        return pub
+
+    def accept(self) -> None:
+        """After the cascade: keep the emission indices of accepted frames
+        (``acc``, harvest order) apart from the dropped ones."""
+        if self.drops:
+            keep = np.ones(self.n, dtype=bool)
+            for lo, hi in self.drops:
+                keep[lo:hi] = False
+            self.acc = self.orig[keep]
+
+    def dropped(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(emission indices, arrival times) of the dropped frames."""
+        idx = np.concatenate([np.arange(lo, hi, dtype=np.int64)
+                              for lo, hi in self.drops])
+        return self.orig[idx], self.arr[idx]
 
 
 @dataclass
@@ -235,6 +291,7 @@ class _Plan:
 
     n: int
     start: int
+    n_dropped: int = 0
     open_window_at: int = 0
     sizes: Optional[np.ndarray] = None
     qplans: List[_QueuePlan] = field(default_factory=list)
@@ -324,8 +381,15 @@ def _cascade(group: List[_QueuePlan], free: int, burst: int, cost_fn,
 
     Each iteration is one event-loop round the lcore actually harvests in:
     the earliest time both the lcore is free and something is published.
-    Queues are serviced in assignment order with the same float cost
-    accumulation as ``poll_at`` (order matters for the final rounding).
+    Between two such rounds a queue's tail stands still, so its next
+    publish is index arithmetic on its arrivals: the frame that completes a
+    threshold batch (counted from the last writeback) or the frame that
+    fills the ring, whichever arrives first.  The filling frame writes back
+    the whole cache, a partial batch; arrivals at or before the harvest
+    instant that find the ring full are dropped before the harvest frees
+    slots (the clip, :meth:`_QueuePlan.published_at`).  Queues are serviced
+    in assignment order with the same float cost accumulation as
+    ``poll_at`` (order matters for the final rounding).
     """
     while True:
         t_next: Optional[int] = None
@@ -353,8 +417,16 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
                 epoch_ns: Optional[int], use_jax: bool,
                 info: EpochRunInfo) -> Optional[_Plan]:
     """Pure planning pass: returns a complete :class:`_Plan`, or None (with
-    ``info.fallback_reason`` set) when a validation shows the run would
-    leave the fast-path regime.  Mutates nothing."""
+    ``info.fallback_reason`` set) when the packet pool would exhaust.
+    Mutates nothing.
+
+    The wire pass (phase A) does not depend on the rings.  The harvest
+    cascade (phase B) carries each RX ring's occupancy: a ring that fills
+    writes back its whole cache early (a partial batch) and drops every
+    further arrival up to the next harvest, each dropped frame freeing its
+    buffer at its arrival.  A run whose rings never fill gets the same
+    harvests and writebacks as a threshold-only replay.  The drains and
+    round-trip times (phase C) follow the accepted frames."""
     rng = np.random.default_rng(pattern.seed)
     with span("repro.epoch.schedule"):
         times, sizes = pattern.emission_schedule(int(duration_s * 1e9), rng)
@@ -418,7 +490,7 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
                                                   arr_p[mask], orig_p[mask])
 
     # -- phase B: per-lcore harvest cascade + terminal flush ----------------
-    with span("repro.epoch.cascade"):
+    with span("repro.epoch.cascade") as cascade:
         cost_fn = server.sim_cost.pmd_burst_ns
         lcore_free = list(server._lcore_next_free)
         events: List[Tuple[int, _QueuePlan, int, int]] = []
@@ -437,35 +509,26 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
             lcore_free[i] = _cascade(group, lcore_free[i], lc.burst_size,
                                      cost_fn, events)
         final_now = max([t_flush] + lcore_free)
-
-    # -- validation 1: no RX ring ever fills --------------------------------
-    # before accepting arrival j (0-indexed), in_flight is j minus harvests
-    # strictly earlier (same-round harvests run after delivery); require the
-    # post-accept occupancy j+1-hb to stay < size, which rules out both the
-    # drop path and the full-triggered early writeback
-    with span("repro.epoch.validate"):
+        n_dropped = 0
         for qp in qplans.values():
-            if qp.n == 0:
-                continue
-            ht = np.fromiter((t for t, _ in qp.harvests), dtype=np.int64,
-                             count=len(qp.harvests))
-            hc = np.cumsum(np.fromiter((h for _, h in qp.harvests),
-                                       dtype=np.int64, count=len(qp.harvests)))
-            idx = np.searchsorted(ht, qp.arr, side="left")
-            hb = np.where(idx > 0, hc[np.maximum(idx - 1, 0)], 0)
-            occ = np.arange(1, qp.n + 1, dtype=np.int64) - hb
-            if int(occ.max()) >= qp.ring.size:
-                info.fallback_reason = (
-                    "RX ring would fill (overflow writeback/drop regime)")
-                return None
+            qp.accept()
+            n_dropped += qp.n_drop
+        cascade.set_metadata(dropped=n_dropped)
 
-        # -- validation 2: the packet pool never exhausts -------------------
-        # +1 at each emission, -1 at the harvest round that drains the frame
-        # (the event loop frees at drain time, not at return-wire arrival);
-        # same-time allocs precede frees (loop step order: emit ... drain)
+    # -- validation: the packet pool never exhausts -------------------------
+    # +1 at each emission, -1 at the harvest round that drains the frame
+    # (the event loop frees at drain time, not at return-wire arrival) or,
+    # for a frame dropped at a full ring, at its arrival (``Port.deliver``);
+    # same-time allocs precede frees (loop step order: emit, deliver ...
+    # drain)
+    with span("repro.epoch.validate"):
         free_t = np.empty(n, dtype=np.int64)
         for t, qp, s, h in events:
-            free_t[qp.orig[s:s + h]] = t
+            free_t[qp.acc[s:s + h]] = t
+        for qp in qplans.values():
+            if qp.drops:
+                d_orig, d_arr = qp.dropped()
+                free_t[d_orig] = d_arr
         pool_ports: Dict[int, Tuple[object, List[int]]] = {}
         for pi, port in enumerate(ports):
             pool_ports.setdefault(id(port.pool), (port.pool, []))[1].append(pi)
@@ -502,7 +565,7 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
             handed = np.concatenate(
                 [np.full(h, t, dtype=np.int64) for t, _qi, _qp, _s, h in evs])
             origs = np.concatenate(
-                [qp.orig[s:s + h] for _t, _qi, qp, s, h in evs])
+                [qp.acc[s:s + h] for _t, _qi, qp, s, h in evs])
             lens = sizes[origs]
             port = ports[pi]
             gbps = float(getattr(port, "link_gbps", 0.0))
@@ -523,7 +586,9 @@ def _build_plan(lg, server, pattern, clock, duration_s: float,
         rtts = (np.concatenate([e[3] for e in tagged]) if tagged
                 else np.empty(0, dtype=np.int64))
 
-    return _Plan(n=n, start=start, open_window_at=int(times_abs[0]),
+    info.n_dropped = n_dropped
+    return _Plan(n=n, start=start, n_dropped=n_dropped,
+                 open_window_at=int(times_abs[0]),
                  sizes=sizes, qplans=list(qplans.values()),
                  lcore_free=lcore_free, final_now=final_now, rtts=rtts,
                  meter_bytes=meter_bytes, meter_start=int(meter_start),
@@ -540,36 +605,36 @@ def _commit(lg, server, pattern, clock, plan: _Plan) -> RunReport:
         for qp in plan.qplans:
             if qp.n == 0:
                 continue
-            nbytes = int(plan.sizes[qp.orig].sum())
+            n_acc = len(qp.acc)
+            nbytes = int(plan.sizes[qp.acc].sum())
             ring = qp.ring
-            ring.delivered += qp.n
+            ring.delivered += n_acc
             ring.delivered_bytes += nbytes
-            ring.head += qp.n
-            ring.tail += qp.n
-            ring.published += qp.n
-            rem = qp.n - qp.n_full * qp.W
-            ring.writebacks += qp.n_full + (1 if rem else 0)
-            ring.writeback_sizes.extend([qp.W] * qp.n_full)
-            if rem:
-                ring.writeback_sizes.append(rem)
+            ring.dropped += qp.n_drop
+            ring.head += n_acc
+            ring.tail += n_acc
+            ring.published += n_acc
+            ring.writebacks += len(qp.wb_sizes)
+            ring.writeback_sizes.extend(qp.wb_sizes)
             txr = lg.ports[qp.pi].tx_queues[qp.qi]
-            txr.posted += qp.n
+            txr.posted += n_acc
             txr.posted_bytes += nbytes
-            txr.transmitted += qp.n
+            txr.transmitted += n_acc
             txr.transmitted_bytes += nbytes
-            txr.head += qp.n
-            txr.tail += qp.n
+            txr.head += n_acc
+            txr.tail += n_acc
             qs = server.queue_stats[(qp.pi, qp.qi)]
-            qs.rx_packets += qp.n
+            qs.rx_packets += n_acc
             qs.rx_bytes += nbytes
-            qs.tx_packets += qp.n
+            qs.tx_packets += n_acc
             qs.poll_iterations += len(qp.harvests)
             for _t, h in qp.harvests:
                 qs.record_burst(h)
         server._lcore_next_free[:] = plan.lcore_free
         lg.latency.record_many(plan.rtts)
-        lg.flight.received += plan.n
-        lg.meter.merge_counts(plan.n, plan.meter_bytes,
+        n_acc = plan.n - plan.n_dropped
+        lg.flight.received += n_acc
+        lg.meter.merge_counts(n_acc, plan.meter_bytes,
                               plan.meter_start, plan.meter_end)
         clock.advance_to(plan.final_now)
     rep = lg._report(

@@ -322,13 +322,28 @@ def test_every_documented_epoch_reason_is_in_the_closed_enum():
             "server and loadgen port lists differ",
             "RX ring not idle",
             "TX ring not idle",
-            "RX ring would fill (overflow writeback/drop regime)",
             "packet pool would exhaust",
             "planning failed: ValueError('boom')",
             "server type PrefillServer is not BypassL2FwdServer",
             "partitioned domain execution",
             None):
         validate_epoch_fallback_reason(reason)
+
+
+def test_ring_fill_is_no_fallback_reason():
+    """The planner carries full RX rings (drops and early writebacks), so
+    a filling ring is no reason to leave the fast path."""
+    from repro.core.fastpath import validate_epoch_fallback_reason
+    with pytest.raises(ValueError, match="closed"):
+        validate_epoch_fallback_reason(
+            "RX ring would fill (overflow writeback/drop regime)")
+    ports = _ports(ring=64)
+    lg, srv = LoadGen(ports), _bypass(ports)
+    info = EpochRunInfo()
+    rep = run_epoch_sim(lg, srv, TrafficPattern(rate_gbps=40.0,
+                                                packet_size=1518),
+                        duration_s=DUR, clock=srv.clock, info=info)
+    assert info.fastpath and rep.dropped == info.n_dropped > 0
 
 
 def test_epoch_info_rejects_unknown_reason():

@@ -1,6 +1,6 @@
-"""Epoch-batched fast path (ISSUE 6): bit-identical RunReports vs the event
-loop, epoch-slicing invariants, per-queue writeback thresholds, and the
-alloc-failure attribution bugfix.
+"""Epoch-batched fast path: bit-identical RunReports vs the event loop (in
+and out of the full-RX-ring drop regime), epoch-slicing invariants,
+per-queue writeback thresholds, and the alloc-failure attribution bugfix.
 
 The engine's contract is absolute: for every config, ``engine="epoch"``
 produces the same RunReport as ``engine="event"`` — either through the
@@ -12,25 +12,28 @@ configs must fall back (and trivially match).
 import numpy as np
 import pytest
 
-from repro.core import (BypassL2FwdServer, EpochRunInfo, LoadGen, PacketPool,
-                        Port, SimClock, TrafficPattern, run_epoch_sim)
-from repro.core.fastpath import default_epoch_ns, iter_epoch_slices
-from repro.exp import (DcaConfig, ExperimentConfig, NodeConfig, PoolConfig,
-                       PortConfig, StackConfig, TopologyConfig, TrafficConfig,
-                       Testbed, run_experiment)
+from repro.core import (BypassL2FwdServer, EpochRunInfo, HostCostModel,
+                        LoadGen, PacketPool, Port, SimClock, TrafficPattern,
+                        run_epoch_sim)
+from repro.core.fastpath import (_build_plan, default_epoch_ns,
+                                 iter_epoch_slices)
+from repro.exp import (CostConfig, DcaConfig, ExperimentConfig, NodeConfig,
+                       PoolConfig, PortConfig, StackConfig, TopologyConfig,
+                       TrafficConfig, Testbed, run_experiment, run_testbed)
+from repro.exp.config import LinkConfig
 from repro.exp.testbed import effective_writeback_threshold
 from repro.exp.topology import Cluster
 
 
 def build(n_queues=4, ring=1024, wb=32, burst=64, n_lcores=4, gbps=40.0,
-          lat=1000, pool_slots=8192, nports=1):
+          lat=1000, pool_slots=8192, nports=1, cost=None):
     pools = [PacketPool(pool_slots, 2048) for _ in range(nports)]
     ports = [Port.make(pools[i], ring_size=ring, writeback_threshold=wb,
                        n_queues=n_queues, link_gbps=gbps, link_latency_ns=lat)
              for i in range(nports)]
     server = BypassL2FwdServer(ports, burst_size=burst, n_lcores=n_lcores)
     clock = SimClock()
-    server.attach_clock(clock)
+    server.attach_clock(clock, cost=cost)
     return server, ports, clock
 
 
@@ -49,20 +52,32 @@ def queue_stats_key(server):
             for k, v in server.per_queue_stats().items()}
 
 
+def ring_key(ports):
+    """Every RX and TX ring counter, with each writeback's size in order."""
+    return tuple(
+        (tuple(r.writeback_sizes), r.writebacks, r.delivered,
+         r.delivered_bytes, r.dropped, r.head, r.tail, r.published,
+         t.posted, t.posted_bytes, t.transmitted, t.transmitted_bytes,
+         t.head, t.tail)
+        for p in ports for r, t in zip(p.rx_queues, p.tx_queues))
+
+
 def run_pair(pattern, dur, use_jax=False, **kw):
     """One config, both engines, fresh state each: returns both observations
     plus the epoch engine's out-of-band info."""
     server, ports, clock = build(**kw)
     lg = LoadGen(ports)
     rep_e = lg.run_sim(server, pattern, duration_s=dur, clock=clock)
-    ev = (report_key(rep_e), queue_stats_key(server), clock.now_ns)
+    ev = (report_key(rep_e), queue_stats_key(server), clock.now_ns,
+          ring_key(ports))
 
     server2, ports2, clock2 = build(**kw)
     lg2 = LoadGen(ports2)
     info = EpochRunInfo()
     rep_f = run_epoch_sim(lg2, server2, pattern, duration_s=dur, clock=clock2,
                           use_jax=use_jax, info=info)
-    ep = (report_key(rep_f), queue_stats_key(server2), clock2.now_ns)
+    ep = (report_key(rep_f), queue_stats_key(server2), clock2.now_ns,
+          ring_key(ports2))
     return ev, ep, info
 
 
@@ -83,6 +98,16 @@ FASTPATH_CASES = [
      0.001, dict(gbps=0.0, lat=0)),
     ("one-lcore-4q", TrafficPattern(rate_gbps=20.0, packet_size=1518),
      0.002, dict(n_lcores=1)),
+    # whole-ring writeback (threshold None): every publish is a ring fill
+    ("wb-none", TrafficPattern(rate_gbps=5.0, packet_size=1518),
+     0.001, dict(wb=None, ring=64)),
+    # 64B @ 100G overloads 4 lcores: the rings genuinely fill and drop (on
+    # a 100 GbE wire, so that no wire backlog exhausts the pool)
+    ("overload-64B-100G", TrafficPattern(rate_gbps=100.0, packet_size=64),
+     0.0005, dict(gbps=100.0)),
+    # one lcore at ~551 ns/pkt cannot keep up with 256B @ 10G (~205 ns/pkt)
+    ("overload-1q", TrafficPattern(rate_gbps=10.0, packet_size=256),
+     0.001, dict(n_queues=1, n_lcores=1)),
 ]
 
 
@@ -92,22 +117,19 @@ def test_epoch_engine_bit_identical_on_fastpath(name, pattern, dur, kw):
     ev, ep, info = run_pair(pattern, dur, **kw)
     assert info.fastpath, info.fallback_reason  # must NOT have fallen back
     assert info.n_packets > 0
+    assert info.n_dropped == ep[0][5]
     assert ev == ep
 
 
 # -- engine equivalence: fallback configs -------------------------------------
 
 FALLBACK_CASES = [
-    # whole-ring writeback (threshold None) couples publishes to ring-full
-    ("wb-none", TrafficPattern(rate_gbps=5.0, packet_size=1518),
-     0.001, dict(wb=None, ring=64)),
-    # 64B @ 100G overloads 4 lcores: the ring genuinely fills (event loop
-    # drops too) — validation must force the event loop, not approximate
-    ("overload-64B-100G", TrafficPattern(rate_gbps=100.0, packet_size=64),
-     0.0005, {}),
-    # one lcore at ~551 ns/pkt cannot keep up with 256B @ 10G (~205 ns/pkt)
-    ("overload-1q", TrafficPattern(rate_gbps=10.0, packet_size=256),
-     0.001, dict(n_queues=1, n_lcores=1)),
+    # a free PMD never advances its lcore's busy window
+    ("zero-cost", TrafficPattern(rate_gbps=5.0, packet_size=1518), 0.0005,
+     dict(cost=HostCostModel(pmd_poll_cycles=0, pmd_per_packet_cycles=0))),
+    # a 64-frame burst cannot fit a 32-descriptor TX ring
+    ("burst-gt-tx-ring", TrafficPattern(rate_gbps=5.0, packet_size=1518),
+     0.0005, dict(ring=32)),
 ]
 
 
@@ -116,6 +138,119 @@ FALLBACK_CASES = [
 def test_epoch_engine_falls_back_and_matches(name, pattern, dur, kw):
     ev, ep, info = run_pair(pattern, dur, **kw)
     assert not info.fastpath and info.fallback_reason
+    assert ev == ep
+
+
+# -- the full-RX-ring drop regime: the clipped cascade -------------------------
+
+# a cost model whose every burst of h frames costs 200 + 100 h ns: with a
+# frame every 100 ns on an ideal wire, every harvest instant is an arrival
+_TICK_COST = HostCostModel(cpu_ghz=1.0, pmd_poll_cycles=200,
+                           pmd_per_packet_cycles=100)
+
+CLIP_CASES = [
+    # burst 8 lets the published backlog pass the threshold of 32, so the
+    # frame that fills the 64-descriptor ring writes back a partial batch
+    ("ring64-thr32", TrafficPattern(rate_gbps=40.0, packet_size=1518),
+     0.0005, dict(n_queues=1, n_lcores=1, ring=64, wb=32, burst=8)),
+    # a threshold that does not divide the ring
+    ("ring100-thr32", TrafficPattern(rate_gbps=40.0, packet_size=1518),
+     0.0005, dict(n_queues=1, n_lcores=1, ring=100, wb=32, burst=32)),
+    # four RSS queues behind one overloaded lcore
+    ("4q-1lcore", TrafficPattern(rate_gbps=40.0, packet_size=1518),
+     0.0005, dict(n_queues=4, n_lcores=1, ring=128, wb=32, burst=32)),
+    # 1,250 B at 100 Gbit/s is a frame every 100 ns; bursts of 8 take 1 us
+    ("harvest-at-arrival", TrafficPattern(rate_gbps=100.0, packet_size=1250),
+     0.0002, dict(n_queues=1, n_lcores=1, ring=64, wb=16, burst=8, gbps=0.0,
+                  lat=0, cost=_TICK_COST)),
+]
+
+
+@pytest.mark.parametrize("use_jax", [False, True], ids=["epoch", "epoch-jit"])
+@pytest.mark.parametrize("name,pattern,dur,kw", CLIP_CASES,
+                         ids=[c[0] for c in CLIP_CASES])
+def test_full_ring_clip_is_bit_identical(name, pattern, dur, kw, use_jax):
+    """Overloaded rings stay on the fast path: drops, partial full-ring
+    writebacks and every counter equal the event loop's."""
+    ev, ep, info = run_pair(pattern, dur, use_jax=use_jax, **kw)
+    assert info.fastpath, info.fallback_reason
+    assert info.used_jax == use_jax
+    assert info.n_dropped == ep[0][5] > 0
+    assert ev == ep
+
+
+def _plan(pattern, dur, **kw):
+    server, ports, clock = build(**kw)
+    return _build_plan(LoadGen(ports), server, pattern, clock, dur, None,
+                       False, EpochRunInfo())
+
+
+@pytest.mark.parametrize("name,pattern,dur,kw", CLIP_CASES[:2],
+                         ids=[c[0] for c in CLIP_CASES[:2]])
+def test_full_ring_writeback_is_partial(name, pattern, dur, kw):
+    """The filling frame writes back fewer than a threshold's descriptors,
+    before the quiet-wire flush, in the event loop and in the plan."""
+    server, ports, clock = build(**kw)
+    LoadGen(ports).run_sim(server, pattern, duration_s=dur, clock=clock)
+    sizes = ports[0].rx_queues[0].writeback_sizes
+    assert any(s < kw["wb"] for s in sizes[:-1])
+    qp, = _plan(pattern, dur, **kw).qplans
+    assert qp.wb_sizes == sizes
+
+
+def test_clip_drops_arrivals_at_the_harvest_instant():
+    """An arrival at the very instant of a harvest that finds the ring full
+    is dropped before the harvest frees its slot."""
+    _name, pattern, dur, kw = CLIP_CASES[3]
+    qp, = _plan(pattern, dur, **kw).qplans
+    harvest_t = {t for t, _h in qp.harvests}
+    last_dropped = {int(qp.arr[hi - 1]) for _lo, hi in qp.drops}
+    assert harvest_t & last_dropped
+
+
+def _l2fwd(nports, rate_gbps):
+    """DPDK l2fwd's defaults at Fig. 3(a)'s 1- and 4-NIC points: a queue and
+    an lcore per 100 GbE port, 1,024 descriptors, burst 32, 1,518 B frames
+    in a 4 ms trial, max(ports x (2,048 + 32 + lcores x 256), 8,192) mbufs."""
+    port = PortConfig(n_queues=1, ring_size=1024, writeback_threshold=32,
+                      link=LinkConfig(gbps=100.0, latency_ns=1000))
+    return ExperimentConfig(
+        pool=PoolConfig(n_slots=max(nports * (2080 + nports * 256), 8192),
+                        slot_size=2176),
+        ports=(port,) * nports,
+        stack=StackConfig(kind="bypass", burst_size=32, n_lcores=nports,
+                          cost=CostConfig(cpu_ghz=2.0, pmd_poll_cycles=150,
+                                          pmd_per_packet_cycles=1100)),
+        traffic=TrafficConfig(mode="open_loop", rate_gbps=rate_gbps,
+                              packet_size=1518, duration_s=0.004,
+                              max_tx_burst=64))
+
+
+def _testbed_run(cfg, info=None):
+    tb = Testbed.build(cfg)
+    rep = run_testbed(tb, info=info)
+    return (rep.to_dict(), queue_stats_key(tb.server), tb.clock.now_ns,
+            ring_key(tb.devs))
+
+
+@pytest.fixture(scope="module", params=[(1, 25.6), (4, 102.4)],
+                ids=["1port-25.6G", "4port-102.4G"])
+def dropping_trial(request):
+    """A Fig. 3(a) search's dropping trial and its event-loop run."""
+    cfg = _l2fwd(*request.param)
+    return cfg, _testbed_run(cfg.with_traffic(engine="event"))
+
+
+@pytest.mark.parametrize("use_jax", [False, True], ids=["epoch", "epoch-jit"])
+def test_l2fwd_search_dropping_trial_is_bit_identical(dropping_trial,
+                                                      use_jax):
+    """The trial of each Fig. 3(a) search that drops runs on the fast path."""
+    cfg, ev = dropping_trial
+    info = EpochRunInfo()
+    ep = _testbed_run(
+        cfg.with_traffic(engine="epoch-jit" if use_jax else "epoch"), info)
+    assert info.fastpath, info.fallback_reason
+    assert info.n_dropped == ep[0]["dropped"] > 0
     assert ev == ep
 
 
@@ -149,12 +284,13 @@ def test_epoch_jit_raises_when_device_pass_fails(monkeypatch):
     ("epoch", TrafficPattern(rate_gbps=40.0, packet_size=1518), "epoch"),
     ("epoch-jit", TrafficPattern(rate_gbps=40.0, packet_size=1518),
      "epoch-jit"),
-    # 64B @ 100G overloads 4 lcores: the exact fallback runs the event loop
-    ("epoch-jit", TrafficPattern(rate_gbps=100.0, packet_size=64), "event"),
+    # 4 buffers starve the run: the exact fallback runs the event loop
+    ("epoch-jit", TrafficPattern(rate_gbps=40.0, packet_size=1518), "event"),
 ], ids=["epoch", "epoch-jit", "epoch-jit-fallback"])
 def test_info_engine_names_what_ran(engine, pattern, ran):
     cfg = ExperimentConfig(
-        pool=PoolConfig(n_slots=8192, slot_size=2048),
+        pool=PoolConfig(n_slots=8192 if ran != "event" else 4,
+                        slot_size=2048),
         ports=(PortConfig(n_queues=4, ring_size=1024,
                           writeback_threshold=32),),
         stack=StackConfig(kind="bypass", burst_size=64, n_lcores=4),

@@ -92,11 +92,11 @@ def test_merge_from_still_aggregates_kernel_stats_and_buckets():
 
 # -- host spans ---------------------------------------------------------------
 
-def _span_cfg(rate_gbps, packet_size):
+def _span_cfg(rate_gbps, packet_size, pool_slots=8192):
     from repro.exp import (ExperimentConfig, PoolConfig, PortConfig,
                            StackConfig, TrafficConfig)
     return ExperimentConfig(
-        pool=PoolConfig(n_slots=8192, slot_size=2048),
+        pool=PoolConfig(n_slots=pool_slots, slot_size=2048),
         ports=(PortConfig(n_queues=4, ring_size=1024,
                           writeback_threshold=32),),
         stack=StackConfig(kind="bypass", burst_size=64, n_lcores=4),
@@ -171,16 +171,18 @@ def test_span_is_one_shared_no_op_without_a_profiler():
         inner.set_metadata(rounds=3)
 
 
-@pytest.mark.parametrize("rate,size,ran,tree", [
-    (40.0, 1518, "epoch-jit", _FAST_TREE),
-    # 64 B at 100 Gbit/s fills a ring: planned, then the event loop runs
-    (100.0, 64, "event", _EVENT_TREE),
-], ids=["device-path", "fallback"])
+@pytest.mark.parametrize("rate,size,slots,ran,tree", [
+    (40.0, 1518, 8192, "epoch-jit", _FAST_TREE),
+    # 64 B at 100 Gbit/s fills the rings: the plan drops, on the device path
+    (100.0, 64, 8192, "epoch-jit", _FAST_TREE),
+    # 4 buffers would exhaust: planned, then the event loop runs
+    (40.0, 1518, 4, "event", _EVENT_TREE),
+], ids=["device-path", "device-path-drops", "fallback"])
 def test_a_traced_experiment_records_its_spans_nested(tmp_path, rate, size,
-                                                      ran, tree):
+                                                      slots, ran, tree):
     from repro.core import EpochRunInfo
     from repro.exp import run_experiment
-    cfg = _span_cfg(rate, size)
+    cfg = _span_cfg(rate, size, slots)
     info = EpochRunInfo()
     rep, spans = _recorded_spans(tmp_path,
                                  lambda: run_experiment(cfg, info=info))
@@ -192,6 +194,9 @@ def test_a_traced_experiment_records_its_spans_nested(tmp_path, rate, size,
     loops = [s for s in spans if s[0] == "repro.loadgen.event_loop"]
     if ran == "event":
         assert len(loops) == 1 and loops[0][3]["rounds"] > 0
+    else:
+        cascade, = [s for s in spans if s[0] == "repro.epoch.cascade"]
+        assert cascade[3]["dropped"] == info.n_dropped == rep.dropped
     # the spans touch no simulated state
     assert rep.to_dict() == run_experiment(cfg).to_dict()
 
