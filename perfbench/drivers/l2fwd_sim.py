@@ -3,11 +3,11 @@ search, replayed from the traffic file's recorded schedule of offered rates.
 
 Each trial is one open-loop l2fwd experiment through ``run_experiment``
 with the configuration's engine (``epoch-jit``: the epoch planner on the
-host, the wire scan on the device).  A trial whose rings would overflow
-falls back to the per-event loop inside the program, as it does inside the
-search itself.  The window runs whole searches: the schedule, in an order
-drawn from the seed for each search, again and again until ``--seconds``
-have passed and the search under way is complete.
+host, the wire scan on the device).  A trial that overloads its rings stays
+on that engine: the planner drops the arrivals that find a ring full, as
+the per-event loop would.  The window runs whole searches: the schedule,
+in an order drawn from the seed for each search, again and again until
+``--seconds`` have passed and the search under way is complete.
 
 After the window every trial's ``RunReport`` is compared exactly with the
 plain reference's report for its schedule entry.
@@ -109,7 +109,6 @@ class Cell:
         frames = sum(t["frames"] for t in self.trials)
         on_device = [t for t in self.trials
                      if t["engine"] == self.cfg["engine"] and t["used_jax"]]
-        in_loop = [t for t in self.trials if t["engine"] == "event"]
         failed = sum(1 for t in self.trials if t["error"] is not None)
         return {
             "attempted": len(self.trials), "failed": failed,
@@ -122,8 +121,6 @@ class Cell:
                 "frames": frames, "device_trials": len(on_device),
                 "device_frames": sum(t["frames"] for t in on_device),
                 "device_wall_s": sum(t["wall_s"] for t in on_device),
-                "event_trials": len(in_loop),
-                "event_wall_s": sum(t["wall_s"] for t in in_loop),
                 "steered": self.cfg["n_queues"] > 1},
         }
 

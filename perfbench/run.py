@@ -60,12 +60,13 @@ def _environment(rehearse: bool) -> None:
         os.environ["JAX_PLATFORMS"] = "cpu"
 
 
-def per_layer(spec, wl, window, trace, devs, cfg):
-    """Each per-layer metric's reader, given what the run observed; a
+def per_layer(spec, wl, window, trace, trace_path, devs, cfg):
+    """Each per-layer metric's reader, given what the run observed (the
+    trace with the program's spans, and the profile it was read from); a
     reader that finds nothing to read returns None and is left out."""
     from perfbench import devices
-    ctx = {"window": window, "trace": trace, "config": cfg,
-           "chips": len(devs),
+    ctx = {"window": window, "trace": trace, "trace_path": trace_path,
+           "config": cfg, "chips": len(devs),
            "peaks": devices.peaks(devs[0].device_kind)}
     out = {}
     for m in specmod.per_layer(spec, wl["name"]):
@@ -73,6 +74,16 @@ def per_layer(spec, wl, window, trace, devs, cfg):
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
+
+
+def breakdown(trace) -> dict:
+    """The ten device ops that took most time, and the ten largest totals
+    of idle device time by the innermost span over it, the program's
+    ``repro.*`` spans included."""
+    from perfbench import spans
+    from perfbench import trace as tr
+    return {"device_ops": tr.top_ops(trace, k=10),
+            "idle_gaps": spans.idle_gaps(trace, k=10)}
 
 
 def main(argv=None) -> int:
@@ -85,6 +96,7 @@ def main(argv=None) -> int:
 
     import jax
     from perfbench import devices
+    from perfbench import spans
     from perfbench import trace as tr
     from perfbench.compiles import CompileCounter
     try:
@@ -136,12 +148,13 @@ def main(argv=None) -> int:
         return 0
     device = dict(devices.describe(devs), memory_peak_bytes=peak)
     if tracing:
-        reduced = tr.load(tr.find_xplane(str(trace_dir)))
+        path = tr.find_xplane(str(trace_dir))
+        reduced = spans.load(path)
         device.update(busy_s=tr.busy_s(reduced), window_s=tr.window_s(reduced))
-        result["metrics"] = per_layer(spec, wl, window, reduced, devs, cfg)
+        result["metrics"] = per_layer(spec, wl, window, reduced, path, devs,
+                                      cfg)
         result["device"] = device
-        result["breakdown"] = {"device_ops": tr.top_ops(reduced),
-                               "idle_gaps": tr.idle_gaps(reduced)}
+        result["breakdown"] = breakdown(reduced)
     else:
         metrics = {}
         for m in specmod.end_to_end(spec, wl["name"]):

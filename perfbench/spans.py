@@ -12,8 +12,8 @@ device planes and the window span.
 On the host's single Python thread the spans nest five deep inside a
 ``perfbench.trial``.  :func:`idle_gaps` cuts each idle stretch of the
 device at span edges and charges each piece to the innermost span that
-covers it, at any depth (``perfbench.trace.idle_gaps`` charges a whole
-stretch by its midpoint, looking back three spans).
+covers it, at any depth; ``perfbench/run.py`` gives the largest totals
+as the traced result's ``breakdown.idle_gaps``.
 """
 from __future__ import annotations
 

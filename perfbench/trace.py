@@ -1,5 +1,6 @@
-"""Reduction of a JAX profiler trace to device busy time, idle gaps and
-kernel time by stable name.
+"""Reduction of a JAX profiler trace to device busy time and kernel time by
+stable name (``perfbench/spans.py`` adds the program's spans and the idle
+time charged to them).
 
 The profiler writes ``<dir>/plugins/profile/<time>/<host>.xplane.pb``.
 :func:`load` reads it with ``jax.profiler.ProfileData`` into plain
@@ -23,7 +24,7 @@ import glob
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 WINDOW_SPAN = "perfbench.window"
 _MODULE_ID = re.compile(r"\(\d+\)$")
@@ -179,31 +180,3 @@ def top_ops(tr: Trace, k: int = 10) -> List[List]:
     return [[n, v / n_dev]
             for n, v in sorted(tot.items(), key=lambda kv: -kv[1])[:k]]
 
-
-def idle_gaps(tr: Trace, k: int = 10) -> List[List]:
-    """Idle device seconds inside the window, by the innermost benchmark
-    host span that covers each gap's midpoint; the ``k`` largest totals,
-    averaged over devices."""
-    lo, hi = tr.window()
-    host = sorted((s for s in tr.host_spans if s.name != WINDOW_SPAN),
-                  key=lambda s: s.start_ns)
-    starts = [s.start_ns for s in host]
-    tot: Dict[str, float] = {}
-    for ops in tr.device_ops.values():
-        busy = union(clip(ops, lo, hi))
-        edges = [lo] + [x for ab in busy for x in ab] + [hi]
-        for a, b in zip(edges[::2], edges[1::2]):
-            if b <= a:
-                continue
-            mid = (a + b) / 2
-            inner: Optional[Span] = None
-            i = bisect.bisect_right(starts, mid) - 1
-            for s in host[max(0, i - 3):i + 1]:  # spans nest a few deep
-                if s.start_ns <= mid < s.end_ns and (
-                        inner is None or s.dur_ns < inner.dur_ns):
-                    inner = s
-            name = inner.name if inner is not None else WINDOW_SPAN
-            tot[name] = tot.get(name, 0.0) + (b - a) / 1e9
-    n_dev = max(1, len(tr.device_ops))
-    return [[n, v / n_dev]
-            for n, v in sorted(tot.items(), key=lambda kv: -kv[1])[:k]]

@@ -6,7 +6,10 @@ ring; a rehearsal's shorter trials never do), each under a
 
     python3 tests/perfbench/data/record_spans.py <out.xplane.pb>
 
-On a TPU; ``JAX_PLATFORMS=cpu`` records the same spans on the CPU.
+On a TPU; ``JAX_PLATFORMS=cpu`` records the same spans on the CPU.  The
+fixture dates from a program whose dropping trial still fell back; the
+planner now clips full rings and keeps every trial of this cell on the
+device path, so the script finds no event-loop trial and stops.
 """
 import shutil
 import sys
@@ -32,7 +35,9 @@ def main(out: str) -> int:
     warm = [cell._trial(i) for i in range(len(cell.rates))]  # compiles too
     fast = min((t for t in warm if t["used_jax"]),
                key=lambda t: t["frames"])["entry"]
-    slow = next(t["entry"] for t in warm if t["engine"] == "event")
+    slow = next((t["entry"] for t in warm if t["engine"] == "event"), None)
+    if slow is None:
+        raise SystemExit("no trial of the cell falls back to the event loop")
     with tempfile.TemporaryDirectory() as tmp:
         jax.profiler.start_trace(tmp, profiler_options=tr.options(jax))
         with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):

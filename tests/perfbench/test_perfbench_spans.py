@@ -6,10 +6,11 @@ under a ``perfbench.trial`` span (``data/record_spans.py``)."""
 import random
 import shutil
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
-from perfbench import devices, spans, spec
+from perfbench import devices, run, spans, spec
 from perfbench import trace as tr
 
 DATA = Path(__file__).parent / "data"
@@ -17,12 +18,13 @@ RECORDED = DATA / "tiny_v5e_spans.xplane.pb"
 OLD = DATA / "tiny_v5e.xplane.pb"
 DEV = "/device:TPU:0"
 NEW = ("testbed_build_ms.sim", "planner_self_ms.sim",
-       "epoch_pass_host_us.sim", "event_round_us.sim")
+       "epoch_pass_host_us.sim")
 B = spec.load()
+_V5E = SimpleNamespace(device_kind="TPU v5 lite")
 EXISTING = [m["name"] for m in B["per_layer"] if m["name"] not in NEW]
 COUNTERS = {"searches": 1, "trials": 2, "frames": 8464, "device_trials": 1,
-            "device_frames": 32, "device_wall_s": 0.01, "event_trials": 1,
-            "event_wall_s": 0.2, "steered": False, "compiles_in_window": 0}
+            "device_frames": 32, "device_wall_s": 0.01, "steered": False,
+            "compiles_in_window": 0}
 
 
 def _trace(host, ops=(), lo=0, hi=100):
@@ -131,6 +133,42 @@ def test_load_adds_the_program_spans_with_their_args(recorded):
     assert sum(s.name == "repro.experiment" for s in recorded.host_spans) == 2
 
 
+def test_breakdown_charges_idle_time_to_the_program_spans(recorded):
+    """The traced result's breakdown: its idle gaps are the span sweep's ten
+    largest totals, most of them ``repro.*`` spans; its ops the trace's."""
+    got = run.breakdown(recorded)
+    assert got["device_ops"] == tr.top_ops(recorded, k=10)
+    assert got["idle_gaps"] == spans.idle_gaps(recorded)[:10]
+    names = [n for n, _v in got["idle_gaps"]]
+    assert names[0] == "repro.loadgen.event_loop"
+    assert {"repro.testbed.port", "repro.epoch.pass"} <= set(names)
+    idle = tr.window_s(recorded) - tr.busy_s(recorded)
+    ours = sum(v for n, v in got["idle_gaps"] if n.startswith("repro."))
+    assert ours > 0.9 * idle
+
+
+@pytest.mark.parametrize("path", [RECORDED, OLD], ids=["spans", "old"])
+def test_harness_reads_the_per_layer_metrics_as_before(path, tmp_path,
+                                                       monkeypatch):
+    """``run.per_layer`` given the trace with the program's spans and its
+    path reads every metric of the cell as the readers did given the plain
+    trace, finding the profile by its window."""
+    d = tmp_path / "cell" / "plugins" / "profile" / "1"
+    d.mkdir(parents=True)
+    shutil.copy(path, d / "host.xplane.pb")
+    monkeypatch.setattr(spans, "TRACE_ROOT", tmp_path)
+    monkeypatch.setattr(spans, "_LOADED", {})
+    before = _ctx(path, trace_path=False)
+    wl = spec.workload(B, "l2fwd-1port.msb")
+    want = {m["name"]: spec.reader(m["name"]).read(before)
+            for m in spec.per_layer(B, wl["name"])}
+    got = run.per_layer(B, wl, before["window"], spans.load(str(path)),
+                        str(path), [_V5E], before["config"])
+    assert {n: v["value"] for n, v in got.items()} == \
+        {n: v for n, v in want.items() if v is not None}
+    assert got and all(v["unit"] for v in got.values())
+
+
 def test_idle_time_goes_to_the_program_spans(recorded):
     gaps = dict(spans.idle_gaps(recorded))
     idle = tr.window_s(recorded) - tr.busy_s(recorded)
@@ -165,15 +203,6 @@ def test_epoch_pass_host_us_by_hand():
     want = sum(b - a for _n, a, b, _s in passes) / 1 / 1e3
     got = spec.reader("epoch_pass_host_us.sim").read(_ctx(RECORDED))
     assert len(passes) == 2 and got == pytest.approx(want)
-
-
-def test_event_round_us_by_hand():
-    loops = [s for s in _raw_host_spans(RECORDED)
-             if s[0] == "repro.loadgen.event_loop"]
-    want = sum(b - a for _n, a, b, _s in loops) \
-        / sum(st["rounds"] for *_x, st in loops) / 1e3
-    got = spec.reader("event_round_us.sim").read(_ctx(RECORDED))
-    assert len(loops) == 1 and got == pytest.approx(want)
 
 
 def test_readers_find_the_run_profile_by_its_window(tmp_path, monkeypatch):

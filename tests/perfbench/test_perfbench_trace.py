@@ -5,6 +5,7 @@ from pathlib import Path
 
 import pytest
 
+from perfbench import spans
 from perfbench import trace as tr
 
 RECORDED = Path(__file__).parent / "data" / "tiny_v5e.xplane.pb"
@@ -56,7 +57,7 @@ def test_kernel_time_by_stable_name(trace):
 
 
 def test_idle_gaps_name_the_host_span_and_add_up(trace):
-    gaps = tr.idle_gaps(trace)
+    gaps = spans.idle_gaps(trace)
     assert gaps[0][0] == "perfbench.trial"
     idle = tr.window_s(trace) - tr.busy_s(trace)
     assert sum(v for _n, v in gaps) == pytest.approx(idle)
