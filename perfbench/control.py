@@ -15,7 +15,6 @@ line per seed.  ``--rehearse`` runs it on the CPU at tiny sizes.
 """
 import argparse
 import contextlib
-import importlib
 import json
 import sys
 from pathlib import Path
@@ -42,7 +41,7 @@ def main(argv=None) -> int:
     import jax
     from perfbench import devices
     devs = devices.check(jax, 1, allow_cpu=args.rehearse)
-    driver = importlib.import_module(f"perfbench.drivers.{cfg['driver']}")
+    driver = specmod.driver(cfg)
     names = {"all": list(driver.VARIANTS), "none": []}.get(
         args.variants, args.variants.split(","))
     for seed in (int(s) for s in args.seeds.split(",")):

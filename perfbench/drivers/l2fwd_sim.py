@@ -52,6 +52,12 @@ def experiment_config(cfg: Dict, traffic: Dict, rate_gbps: float, seed: int):
 
 
 class Cell:
+    """A driver for a variant of l2fwd subclasses this and overrides
+    ``experiment_config``, which builds each trial's ``ExperimentConfig``
+    from the configuration, the traffic, the trial's rate and its seed."""
+
+    experiment_config = staticmethod(experiment_config)
+
     def __init__(self, cfg: Dict, traffic: Dict, seed: int,
                  rehearse: bool = False):
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
@@ -67,7 +73,7 @@ class Cell:
         info = EpochRunInfo()
         t0 = time.perf_counter()
         try:
-            rep = run_experiment(experiment_config(
+            rep = run_experiment(self.experiment_config(
                 self.cfg, self.traffic, self.rates[i],
                 generate.trial_seed(self.seed, i)), info=info)
             error = None

@@ -20,22 +20,49 @@ from __future__ import annotations
 
 import math
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 
-def emission_schedule(kind: str, rate_gbps: float, packet_size: int,
-                      duration_ns: int, seed: int):
+def frame_table(frame_mix) -> Tuple[float, np.ndarray]:
+    """Mean frame (float64 bytes) and draw table of a frame-size mix, a
+    list of ``[frame bytes, weight]`` pairs: each size repeated by its
+    integer weight, in the listed order."""
+    sizes = [int(b) for b, _w in frame_mix]
+    weights = [w for _b, w in frame_mix]
+    if not sizes or any(not isinstance(w, int) or w < 1 for w in weights) \
+            or any(b < 64 for b in sizes):
+        raise ValueError(f"frame_mix needs sizes >= 64 B and integer "
+                         f"weights >= 1: {frame_mix!r}")
+    mean = (np.float64(sum(b * w for b, w in zip(sizes, weights)))
+            / np.float64(sum(weights)))
+    return float(mean), np.repeat(np.asarray(sizes, dtype=np.int32), weights)
+
+
+def emission_schedule(kind: str, rate_gbps: float,
+                      packet_size: Optional[int], duration_ns: int,
+                      seed: int, *, frame_mix=None):
     """Emission times (int64 ns) and sizes (int32) of one trial: a uniform
-    grid, or Poisson arrivals drawn block by block from ``seed``."""
-    pps = rate_gbps * 1e9 / 8.0 / packet_size
+    grid, or Poisson arrivals drawn block by block from ``seed``.
+
+    Every frame is ``packet_size`` bytes, or, with ``frame_mix`` instead,
+    each is drawn from the mix's table after the times: uniformly over its
+    entries, by ``np.random.default_rng(seed)`` (for Poisson arrivals the
+    generator that drew the times, continued)."""
+    if (frame_mix is None) == (packet_size is None):
+        raise ValueError("give packet_size or frame_mix, not both or neither")
+    if frame_mix is None:
+        mean, table = packet_size, None
+    else:
+        mean, table = frame_table(frame_mix)
+    pps = rate_gbps * 1e9 / 8.0 / mean
     gap_ns = 1e9 / pps
+    rng = np.random.default_rng(seed)
     if kind == "uniform":
         n = int(duration_ns * 1e-9 * pps)
         times = (np.arange(n, dtype=np.float64) * gap_ns).astype(np.int64)
     elif kind == "poisson":
-        rng = np.random.default_rng(seed)
         block = max(64, int(duration_ns * 1e-9 * pps) + 64)
         chunks, last = [], 0.0
         while last < duration_ns:
@@ -46,7 +73,9 @@ def emission_schedule(kind: str, rate_gbps: float, packet_size: int,
         times = cat[cat < duration_ns].astype(np.int64)
     else:
         raise ValueError(f"unknown arrival kind {kind!r}")
-    return times, np.full(len(times), packet_size, dtype=np.int32)
+    if table is None:
+        return times, np.full(len(times), packet_size, dtype=np.int32)
+    return times, table[rng.integers(0, len(table), size=len(times))]
 
 
 def toeplitz(key: bytes, data: bytes) -> int:
@@ -99,11 +128,14 @@ def simulate(cfg: Dict, kind: str, rate_gbps: float, duration_s: float,
              seed: int, dtype: Optional[str] = None) -> Dict:
     """One trial, event by event; returns its report."""
     nports = cfg["ports"]
-    size_b = cfg["packet_size"]
+    mix = cfg.get("frame_mix")
+    if mix is not None and any(b > cfg["slot_size"] for b, _w in mix):
+        raise ValueError(f"frame_mix sizes exceed slot_size {cfg['slot_size']}")
     gbps, lat = cfg["link_gbps"], cfg["link_latency_ns"]
     burst, max_tx = cfg["burst"], cfg["max_tx_burst"]
-    times, sizes = emission_schedule(kind, rate_gbps, size_b,
-                                     int(duration_s * 1e9), seed)
+    times, sizes = emission_schedule(kind, rate_gbps, cfg.get("packet_size"),
+                                     int(duration_s * 1e9), seed,
+                                     frame_mix=mix)
     n = len(times)
     qtab = [flow_queue(cfg, f) for f in range(cfg["n_flows"])]
     nq = cfg["n_queues"]

@@ -21,7 +21,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -109,8 +108,8 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     compiles = CompileCounter(jax)
 
-    driver = importlib.import_module(f"perfbench.drivers.{cfg['driver']}")
-    cell = driver.Cell(cfg, traffic, args.seed, rehearse=args.rehearse)
+    cell = specmod.driver(cfg).Cell(cfg, traffic, args.seed,
+                                    rehearse=args.rehearse)
     cell.setup()
     setup_s = time.perf_counter() - T0
 

@@ -11,6 +11,7 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -45,6 +46,13 @@ def config_named(name: str, root: Path = ROOT) -> Dict:
     not a cell of ``BENCHMARK.json`` uses it."""
     with open(root / "perfbench" / "configs" / f"{name}.json") as f:
         return json.load(f)
+
+
+def driver(cfg: Dict) -> ModuleType:
+    """The module ``perfbench.drivers.<driver>`` that runs configuration
+    ``cfg``: its ``Cell`` and the ``VARIANTS`` its check can put in the
+    program's place."""
+    return importlib.import_module(f"perfbench.drivers.{cfg['driver']}")
 
 
 def traffic(wl: Dict, root: Path = ROOT) -> Dict:

@@ -23,7 +23,6 @@ import jax  # noqa: E402
 
 from perfbench import spec  # noqa: E402
 from perfbench import trace as tr  # noqa: E402
-from perfbench.drivers import l2fwd_sim  # noqa: E402
 
 WORKLOAD = "l2fwd-1port.msb"
 
@@ -31,7 +30,8 @@ WORKLOAD = "l2fwd-1port.msb"
 def main(out: str) -> int:
     bench = spec.load()
     wl = spec.workload(bench, WORKLOAD)
-    cell = l2fwd_sim.Cell(spec.config(bench, wl), spec.traffic(wl), seed=1)
+    cfg = spec.config(bench, wl)
+    cell = spec.driver(cfg).Cell(cfg, spec.traffic(wl), seed=1)
     warm = [cell._trial(i) for i in range(len(cell.rates))]  # compiles too
     fast = min((t for t in warm if t["used_jax"]),
                key=lambda t: t["frames"])["entry"]

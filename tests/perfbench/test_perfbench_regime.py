@@ -1,19 +1,30 @@
-"""Each simulator cell's traffic is the search it says it is: replaying its
-schedule of rates with ``engine="epoch"`` (which plans the same regime as
-the device engine, with numpy), the ramp-and-bisect rule applied to the
-trials' outcomes visits exactly those rates.  Every trial stays on the
-epoch fast path, those that drop too: the planner's full-ring clip drops
-the arrivals that find a ring full, as the per-event loop would."""
+"""Each cell that replays a search (its traffic has ``rates_gbps``, its
+driver's ``Cell`` an ``experiment_config``) is the search it says it is:
+replaying its schedule of rates through that function with
+``engine="epoch"`` (which plans the same regime as the device engine, with
+numpy), the ramp-and-bisect rule applied to the trials' outcomes visits
+exactly those rates.  Every trial stays on the epoch fast path, those that
+drop too: the planner's full-ring clip drops the arrivals that find a ring
+full, as the per-event loop would."""
 import pytest
 
 from perfbench import spec
-from perfbench.drivers.l2fwd_sim import experiment_config
 from repro.core import EpochRunInfo
 from repro.exp import run_experiment
 
 B = spec.load()
+
+
+def _experiment_config(wl):
+    """The function the cell's driver builds each trial's configuration
+    with, or None for a driver that does not replay a search."""
+    return getattr(spec.driver(spec.config(B, wl)).Cell, "experiment_config",
+                   None)
+
+
 SIM_CELLS = [w["name"] for w in B["workloads"]
-             if spec.config(B, w)["driver"] == "l2fwd_sim"]
+             if "rates_gbps" in spec.traffic(w)
+             and _experiment_config(w) is not None]
 
 
 def search_rates(sustains, start=0.1, refine=4, max_gbps=400.0):
@@ -40,6 +51,7 @@ def test_schedule_is_the_search_and_its_regime(cell):
     wl = spec.workload(B, cell)
     cfg = dict(spec.config(B, wl), engine="epoch")
     traffic = spec.traffic(wl)
+    experiment_config = _experiment_config(wl)
     outcome = {}
 
     def sustains(rate):
