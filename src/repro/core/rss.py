@@ -22,7 +22,8 @@ published Microsoft test vectors (see ``tests/test_rss.py``).
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+import functools
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -60,15 +61,18 @@ def _key_windows(key: bytes, n_input_bits: int) -> np.ndarray:
     return out
 
 
-def _key_byte_tables(windows: np.ndarray) -> List[List[int]]:
+_ByteTables = Tuple[Tuple[int, ...], ...]
+
+
+def _key_byte_tables(windows: np.ndarray) -> _ByteTables:
     """Per-(byte position, byte value) XOR contributions to the Toeplitz hash.
 
     tables[p][v] == XOR of the key windows for the set bits of value ``v`` at
     byte position ``p``.  With these, hashing one 12-byte tuple is 12 plain
-    list lookups — no numpy temporaries, which is what the single-packet
+    tuple lookups — no numpy temporaries, which is what the single-packet
     delivery hot path needs (burst paths keep the vectorized route).
     """
-    tables: List[List[int]] = []
+    tables: List[Tuple[int, ...]] = []
     for p in range(len(windows) // 8):
         w = windows[p * 8 : (p + 1) * 8]
         row = [0] * 256
@@ -78,12 +82,25 @@ def _key_byte_tables(windows: np.ndarray) -> List[List[int]]:
                 if v & (0x80 >> bit):
                     h ^= int(w[bit])
             row[v] = h
-        tables.append(row)
-    return tables
+        tables.append(tuple(row))
+    return tuple(tables)
 
 
-_WINDOWS = _key_windows(DEFAULT_RSS_KEY, FLOW_TUPLE_BYTES * 8)
-_BYTE_TABLES = _key_byte_tables(_WINDOWS)
+@functools.lru_cache(maxsize=16)
+def _key_tables(key: bytes) -> Tuple[np.ndarray, _ByteTables]:
+    """The key windows and per-byte tables of ``key``, built once per key
+    per process and shared by every port and hash that uses it: both are
+    pure functions of the key bytes, so sharing is safe once the windows
+    are read-only and the tables are tuples."""
+    windows = _key_windows(key, FLOW_TUPLE_BYTES * 8)
+    windows.setflags(write=False)
+    return windows, _key_byte_tables(windows)
+
+
+def key_tables_built() -> int:
+    """How many keys' tables this process has built so far; a port whose
+    configure leaves it unchanged reused tables already built."""
+    return _key_tables.cache_info().misses
 
 
 def _hash_with_windows(flow_bytes: np.ndarray, windows: np.ndarray) -> np.ndarray:
@@ -103,7 +120,7 @@ def toeplitz_hash_vec(flow_bytes: np.ndarray, key: Optional[bytes] = None) -> np
     ``flow_bytes`` is an (N, 12) uint8 array of big-endian 4-tuples
     (src_ip, dst_ip, src_port, dst_port).  Returns (N,) uint32 hashes.
     """
-    windows = _WINDOWS if key is None else _key_windows(key, FLOW_TUPLE_BYTES * 8)
+    windows, _ = _key_tables(DEFAULT_RSS_KEY if key is None else bytes(key))
     return _hash_with_windows(flow_bytes, windows)
 
 
@@ -131,13 +148,10 @@ class RssIndirection:
         if table_size < n_queues:
             raise ValueError("table_size must be >= n_queues")
         self.n_queues = int(n_queues)
-        self.key = DEFAULT_RSS_KEY if key is None else key
-        # key windows precomputed once — steering is on the per-burst hot path
-        self._windows = (_WINDOWS if key is None
-                         else _key_windows(key, FLOW_TUPLE_BYTES * 8))
-        # per-byte lookup tables for the scalar (single-packet) path
-        self._byte_tables = (_BYTE_TABLES if key is None
-                             else _key_byte_tables(self._windows))
+        self.key = DEFAULT_RSS_KEY if key is None else bytes(key)
+        # key windows for the per-burst hot path and per-byte lookup tables
+        # for the scalar (single-packet) path, shared by every equal key
+        self._windows, self._byte_tables = _key_tables(self.key)
         self.table = (np.arange(table_size) % n_queues).astype(np.int32)
         self._table_list: List[int] = self.table.tolist()
 

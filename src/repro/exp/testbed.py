@@ -15,6 +15,7 @@ from repro.core import (BurstPlan, BypassL2FwdServer, EthConf, EthDev,
                         EventScheduler, KernelStackServer, LoadGen,
                         NetworkStack, PacketPool, PipelineServer,
                         QueueTelemetry, SimClock)
+from repro.core.rss import key_tables_built
 from repro.core.telemetry import span
 
 from .config import CostConfig, DcaConfig, ExperimentConfig, StackConfig
@@ -137,7 +138,8 @@ class Testbed:
                 pool = PacketPool(cfg.pool.n_slots, cfg.pool.slot_size)
             devs: List[EthDev] = []
             for dev_id, pc in enumerate(cfg.ports):
-                with span("repro.testbed.port"):
+                with span("repro.testbed.port") as port_span:
+                    built = key_tables_built()
                     dev = EthDev(pool, dev_id=dev_id).configure(EthConf(
                         n_rx_queues=pc.n_queues, n_tx_queues=pc.n_queues,
                         rss_key=pc.rss.key, rss_table_size=pc.rss.table_size,
@@ -150,6 +152,8 @@ class Testbed:
                                            writeback_threshold=thr)
                         dev.tx_queue_setup(q, pc.ring_size)
                     devs.append(dev.dev_start())
+                    port_span.set_metadata(
+                        rss_tables_built=key_tables_built() - built)
             server = build_stack(effective_stack_config(cfg.stack, cfg.dca),
                                  devs)
             clock: Optional[SimClock] = None

@@ -4,8 +4,8 @@ import json
 
 import pytest
 
-from repro.core import (BypassL2FwdServer, EthDevState, KernelStackServer,
-                        PipelineServer, SimClock)
+from repro.core import (DEFAULT_RSS_KEY, BypassL2FwdServer, EthDevState,
+                        KernelStackServer, PipelineServer, SimClock)
 from repro.exp import (CostConfig, ExperimentConfig, LinkConfig, PoolConfig,
                        PortConfig, RssConfig, StackConfig, TrafficConfig,
                        Testbed, make_server_factory, register_stack,
@@ -168,6 +168,31 @@ def test_run_experiment_msb_mode():
     rep = run_experiment(cfg)
     assert rep.extras["msb_gbps"] > 0
     assert rep.extras["msb_trials"] >= 1
+
+
+def _keyed(key_hex):
+    """Two 1-queue ports, as the l2fwd benchmark configurations set them."""
+    port = PortConfig(ring_size=512, rss=RssConfig(key_hex=key_hex))
+    return ExperimentConfig(
+        ports=(port, port),
+        stack=StackConfig(kind="bypass", n_lcores=2),
+        traffic=TrafficConfig(mode="open_loop", rate_gbps=20.0,
+                              duration_s=0.0002))
+
+
+def test_rebuilding_a_keyed_testbed_builds_no_key_tables():
+    """Every port of every later build reuses the key's tables, and an
+    explicit default key reports exactly what the implicit one does."""
+    from repro.core.rss import key_tables_built
+    cfg = _keyed(DEFAULT_RSS_KEY.hex())
+    Testbed.build(cfg)
+    misses = key_tables_built()
+    Testbed.build(cfg)
+    assert key_tables_built() == misses
+    rep = run_experiment(cfg).to_dict()
+    assert rep == run_experiment(cfg).to_dict()
+    assert rep == run_experiment(_keyed(None)).to_dict()
+    assert rep["received"] > 0
 
 
 def test_sim_time_default_builds_clocked_testbed():

@@ -8,6 +8,8 @@ from repro.core import (BurstPlan, BypassL2FwdServer, KernelStackServer,
                         TrafficPattern, flow_tuple_for_id, rss_skew,
                         toeplitz_hash, toeplitz_hash_vec, write_flow)
 from repro.core.cost import HostCostModel
+from repro.core.rss import (DEFAULT_RSS_KEY, FLOW_TUPLE_BYTES, _key_byte_tables,
+                            _key_tables, _key_windows, key_tables_built)
 
 
 def _flow_bytes(src_ip, dst_ip, sport, dport):
@@ -20,17 +22,20 @@ def _ip(a, b, c, d):
     return (a << 24) | (b << 16) | (c << 8) | d
 
 
+# Microsoft verification-suite vectors: (IPv4 4-tuple, Toeplitz hash)
+_MS_VECTORS = [
+    ((_ip(66, 9, 149, 187), _ip(161, 142, 100, 80), 2794, 1766), 0x51CCC178),
+    ((_ip(199, 92, 111, 2), _ip(65, 69, 140, 83), 14230, 4739), 0xC626B0EA),
+    ((_ip(24, 19, 198, 95), _ip(12, 22, 207, 184), 12898, 38024), 0x5C2B394A),
+    ((_ip(38, 27, 205, 30), _ip(209, 142, 163, 6), 48228, 2217), 0xAFC7327F),
+    ((_ip(153, 39, 163, 191), _ip(202, 188, 127, 2), 44251, 1303), 0x10E828A2),
+]
+
+
 def test_toeplitz_matches_microsoft_vectors():
     """The hash is the real RSS algorithm: verify against the published
     Microsoft verification-suite vectors (IPv4 with ports)."""
-    vectors = [
-        ((_ip(66, 9, 149, 187), _ip(161, 142, 100, 80), 2794, 1766), 0x51CCC178),
-        ((_ip(199, 92, 111, 2), _ip(65, 69, 140, 83), 14230, 4739), 0xC626B0EA),
-        ((_ip(24, 19, 198, 95), _ip(12, 22, 207, 184), 12898, 38024), 0x5C2B394A),
-        ((_ip(38, 27, 205, 30), _ip(209, 142, 163, 6), 48228, 2217), 0xAFC7327F),
-        ((_ip(153, 39, 163, 191), _ip(202, 188, 127, 2), 44251, 1303), 0x10E828A2),
-    ]
-    for args, want in vectors:
+    for args, want in _MS_VECTORS:
         assert toeplitz_hash(_flow_bytes(*args)) == want
 
 
@@ -97,6 +102,54 @@ def test_indirection_rebalance():
     assert (rss.steer(flows) == 0).all()
     with pytest.raises(ValueError):
         rss.rebalance([7] * 128)  # names a queue that doesn't exist
+
+
+# -- key tables, built once per key -------------------------------------------
+
+_OTHER_KEY = bytes(range(7, 7 + 40))
+
+
+@pytest.mark.parametrize("key", [DEFAULT_RSS_KEY, _OTHER_KEY],
+                         ids=["default-key", "other-key"])
+def test_memoised_key_tables_equal_a_fresh_build(key):
+    windows, tables = _key_tables(key)
+    fresh = _key_windows(key, FLOW_TUPLE_BYTES * 8)
+    assert windows.dtype == fresh.dtype and windows.tolist() == fresh.tolist()
+    assert [list(row) for row in tables] == [
+        list(row) for row in _key_byte_tables(fresh)]
+
+
+def test_equal_keys_share_one_read_only_table():
+    """A second port with an equal key (an equal-valued copy, not the same
+    object) builds nothing: it reuses the first port's windows, which nobody
+    can write, while each keeps its own indirection table."""
+    a = RssIndirection(4, key=bytes(bytearray(_OTHER_KEY)))
+    misses = key_tables_built()
+    b = RssIndirection(4, key=bytes(bytearray(_OTHER_KEY)))
+    assert key_tables_built() == misses
+    assert b._windows is a._windows and b._byte_tables is a._byte_tables
+    assert not a._windows.flags.writeable
+    with pytest.raises(ValueError):
+        a._windows[0] = 0
+    before = b.table.copy()
+    a.rebalance([1] * 128)
+    assert (b.table == before).all() and b._table_list == before.tolist()
+    assert (a.table == 1).all()
+
+
+@pytest.mark.parametrize("key", [None, DEFAULT_RSS_KEY,
+                                 bytes(bytearray(DEFAULT_RSS_KEY))],
+                         ids=["no-key", "explicit-key", "equal-copy"])
+def test_shared_tables_still_match_microsoft_vectors(key):
+    """hash_one, steer and toeplitz_hash_vec keep the published hashes with
+    the key given explicitly; with a 128-queue identity table, steer is the
+    hash's low seven bits."""
+    rss = RssIndirection(128, 128, key=key)
+    flows = np.stack([_flow_bytes(*args) for args, _ in _MS_VECTORS])
+    want = np.array([h for _, h in _MS_VECTORS], dtype=np.uint32)
+    assert [rss.hash_one(f) for f in flows] == want.tolist()
+    assert (toeplitz_hash_vec(flows, key=key) == want).all()
+    assert (rss.steer(flows) == want % 128).all()
 
 
 def _mk_bypass(n_queues=4, n_lcores=4, pool_slots=8192, ring=512, **kw):

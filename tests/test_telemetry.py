@@ -212,3 +212,18 @@ def test_event_loop_span_counts_its_rounds(tmp_path):
         duration_s=0.0005, clock=tb.clock, sched=tb.sched, max_rounds=7))
     loop, = [s for s in spans if s[0] == "repro.loadgen.event_loop"]
     assert loop[3]["rounds"] == 7
+
+
+def test_port_span_counts_the_key_tables_its_configure_built(tmp_path):
+    """A key new to the process is built by the first port that uses it and
+    by no other port, in this build or any later one."""
+    from dataclasses import replace
+
+    from repro.exp import PortConfig, RssConfig, Testbed
+    port = PortConfig(rss=RssConfig(key_hex=bytes(range(90, 130)).hex()))
+    cfg = replace(_span_cfg(40.0, 1518), ports=(port, port))
+    _tbs, spans = _recorded_spans(
+        tmp_path, lambda: [Testbed.build(cfg) for _ in range(2)])
+    built = [s[3]["rss_tables_built"] for s in spans
+             if s[0] == "repro.testbed.port"]
+    assert built == [1, 0, 0, 0]
